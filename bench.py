@@ -85,6 +85,15 @@ CRITICAL_PATH_SPANS = frozenset({
     "host.commit",
     "device.apply_deltas",    # wire: server half of the delta push
     "device.schedule_batch",  # wire: server half of the batch call
+    # the scheduling loop's own work and the runtime under it: the wait on
+    # the commit worker (or the inline commits) at a drain, the cache
+    # snapshot update, the housekeeping sweep, and GC pauses and XLA
+    # compiles on whichever thread they hit
+    "scheduling.drain",
+    "cache.snapshot",
+    "scheduling.housekeeping",
+    "runtime.gc",
+    "xla.compile",
 })
 
 

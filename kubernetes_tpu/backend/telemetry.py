@@ -53,6 +53,8 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
+from ..utils import tracing
+
 _recorder: Optional["DeviceTelemetry"] = None
 
 # the jax.monitoring event that fires exactly once per XLA backend compile
@@ -521,8 +523,6 @@ class DeviceTelemetry:
             m.device_transfer_bytes.inc(direction, value=float(nbytes))
         # annotate the active span (device.sync / device.commit.wait) so the
         # bench critical path can see the bytes behind each phase
-        from ..utils import tracing
-
         tracing.annotate(**{f"device.{direction}": int(nbytes)})
 
     def sample_hbm(self) -> Optional[dict]:
@@ -592,28 +592,38 @@ _listener_installed = False
 
 
 def _install_listener() -> None:
-    """Register the jax.monitoring compile listener once per process. The
-    callback itself is disabled-guarded, so a later disable() costs one
-    global read per *compile event* (compiles are rare by definition)."""
+    """Register the jax.monitoring compile listener once per process: it
+    feeds the CompileLedger while telemetry is on and back-fills an
+    ``xla.compile`` span, ``[end - duration, end]`` on the compiling thread,
+    while the tracer is on. ``enable()`` here and ``tracing.enable()`` both
+    install it. Each side is disabled-guarded, so with both off it costs two
+    global reads per *compile event* (compiles are rare by definition)."""
     global _listener_installed
     if _listener_installed:
         return
     try:
         import jax.monitoring as mon
 
-        def _on_duration(name, duration_s, **_kw):
-            t = _recorder
-            if t is None or name != _COMPILE_EVENT:
+        def _on_duration(name, duration_s, **kw):
+            if name != _COMPILE_EVENT:
                 return
-            try:
-                t.ledger.record_compile(duration_s)
-            except Exception:  # noqa: BLE001 — never fail a compile
-                pass
+            end = time.time_ns()
+            t = _recorder
+            if t is not None:
+                try:
+                    t.ledger.record_compile(duration_s)
+                except Exception:  # noqa: BLE001 — never fail a compile
+                    pass
+            tracing.emit("xla.compile", end - int(duration_s * 1e9), end,
+                         fun=kw.get("fun_name", ""))
 
         mon.register_event_duration_secs_listener(_on_duration)
         _listener_installed = True
     except Exception:  # noqa: BLE001 — no monitoring API: ledger stays zero
         _listener_installed = True  # don't retry per enable
+
+
+tracing.on_enable(_install_listener)
 
 
 def enable(metrics=None, ring_capacity: int = 4096) -> DeviceTelemetry:
@@ -716,8 +726,6 @@ def emit_phase_spans(rec: Optional[dict]) -> None:
     or tracing is disabled."""
     if rec is None:
         return
-    from ..utils import tracing
-
     if tracing.get() is None:
         return
     anchor = time.time_ns()

@@ -20,6 +20,7 @@ Flow per batch cycle:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 from collections import deque
@@ -206,12 +207,6 @@ class TPUScheduler(Scheduler):
         # adaptive-sampling rotation start: a device scalar chained from the
         # previous batch's evolved carry (schedule_one.go:475 rotation)
         self._start_carry = None
-        # §5.1 profiling: KTPU_PROFILE_DIR=<dir> captures a JAX profiler
-        # trace of the first KTPU_PROFILE_BATCHES (default 4) batch cycles —
-        # the per-cycle XLA trace-dump analog of scheduler_perf -cpuprofile
-        self._profile_dir = os.environ.get("KTPU_PROFILE_DIR", "")
-        self._profile_batches = int(os.environ.get("KTPU_PROFILE_BATCHES", "4"))
-        self._profiling = False
         # async pipeline (SURVEY §2.7 P3 analog), generalized to a bounded
         # multi-batch in-flight RING: up to ``pipeline_depth`` dispatched
         # batches ride the device at once (oldest commits first), so the
@@ -598,18 +593,26 @@ class TPUScheduler(Scheduler):
         settled state instead of racing a half-committed batch. ONE clock
         read feeds both this gate and the base sweep — two reads straddling
         the tick boundary would skip the flush yet still run the sweep,
-        iterating waiting_pods while the worker parks into it."""
+        iterating waiting_pods while the worker parks into it. A call that
+        runs a sweep (the 1s tick, the 30s flush, or a rebalancer score) is
+        one scheduling.housekeeping span; the others open none."""
+        from ..utils import tracing
+
         if now is None:
             now = self.now_fn()
-        if (self.commit_worker is not None
-                and now - self._last_cleanup >= 1.0
-                and not self.commit_worker.idle()):
-            self.commit_worker.flush()
-        super()._periodic_housekeeping(now)
-        if self.rebalancer is not None:
-            # after the sweep (settled ledgers), gated internally on the
-            # score interval + commit-plane idleness
-            self.rebalancer.maybe_run(now)
+        tick = now - self._last_cleanup >= 1.0
+        sweep = (tick or now - self._last_unsched_flush >= 30.0
+                 or (self.rebalancer is not None and self.rebalancer.due(now)))
+        with (tracing.span("scheduling.housekeeping") if sweep
+              else contextlib.nullcontext()):
+            if (self.commit_worker is not None and tick
+                    and not self.commit_worker.idle()):
+                self._flush_commit_worker()
+            super()._periodic_housekeeping(now)
+            if self.rebalancer is not None:
+                # after the sweep (settled ledgers), gated internally on the
+                # score interval + commit-plane idleness
+                self.rebalancer.maybe_run(now)
 
     def enable_rebalancer(self, **kwargs):
         """Attach the background Rebalancer (controllers/rebalance.py) —
@@ -620,25 +623,6 @@ class TPUScheduler(Scheduler):
         self.rebalancer = Rebalancer(self, now_fn=kwargs.pop(
             "now_fn", self.now_fn), **kwargs)
         return self.rebalancer
-
-    def _maybe_profile(self) -> None:
-        """Start/stop a JAX profiler capture window over the first N batch
-        cycles when KTPU_PROFILE_DIR is set (view with xprof/tensorboard)."""
-        if not self._profile_dir:
-            return
-        if not self._profiling and self.batch_counter == 0:
-            try:
-                jax.profiler.start_trace(self._profile_dir)
-                self._profiling = True
-            except Exception:  # noqa: BLE001 — profiling must never break scheduling
-                self._profile_dir = ""
-        elif self._profiling and self.batch_counter >= self._profile_batches:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:  # noqa: BLE001 — a torn profiler trace must not kill the batch path
-                pass
-            self._profiling = False
-            self._profile_dir = ""
 
     def _flush_batch(self, batched: List[QueuedPodInfo], pod_cycle: int,
                      t_pop: Optional[float] = None) -> None:
@@ -656,7 +640,6 @@ class TPUScheduler(Scheduler):
                             t_pop: Optional[float] = None) -> None:
         from ..utils import tracing
 
-        self._maybe_profile()
         t0 = self.now_fn()
         t_pop = t_pop if t_pop is not None else t0
         mutex = self.commit_plane.device_mutex
@@ -938,10 +921,31 @@ class TPUScheduler(Scheduler):
         if self.commit_worker is not None:
             while self._inflight:
                 self.commit_worker.submit(self._inflight.popleft())
-            self.commit_worker.flush()
+            self._flush_commit_worker()
             return
-        while self._inflight:
-            self._commit_inflight(self._inflight.popleft())
+        from ..utils import tracing
+
+        with tracing.span("scheduling.drain") as sp:
+            landed = 0
+            while self._inflight:
+                self._commit_inflight(self._inflight.popleft())
+                landed += 1
+            if sp is not None:
+                sp.attributes["batches"] = landed
+
+    def _flush_commit_worker(self) -> None:
+        """Block until the commit worker has landed every submitted batch,
+        as one scheduling.drain span counting the batches it landed."""
+        from ..utils import tracing
+
+        worker = self.commit_worker
+        with tracing.span("scheduling.drain") as sp:
+            landed0 = worker.committed
+            try:
+                worker.flush()
+            finally:
+                if sp is not None:
+                    sp.attributes["batches"] = worker.committed - landed0
 
     def _commit_inflight(self, fl: _Inflight) -> None:
         """Land one dispatched batch on the host — on the scheduling thread
@@ -2018,11 +2022,4 @@ class TPUScheduler(Scheduler):
             max_cycles=max_cycles, flush=flush, idle_wait=idle_wait,
             max_no_progress=max_no_progress)
         self._drain_inflight()
-        if self._profiling:  # fewer batches than the window: flush the trace
-            try:
-                jax.profiler.stop_trace()
-            except Exception:  # noqa: BLE001 — a torn profiler trace must not fail the settle
-                pass
-            self._profiling = False
-            self._profile_dir = ""
         return cycles

@@ -27,6 +27,7 @@ from typing import Dict, List, Optional, Tuple
 from ..api.types import Node, Pod
 from ..framework.types import NodeInfo, next_generation
 from ..testing import locktrace
+from ..utils import tracing
 from .snapshot import Snapshot
 
 DEFAULT_ASSUME_TTL = 30.0  # durationToExpireAssumedPod (scheduler.go:311)
@@ -264,7 +265,18 @@ class Cache:
         """Incremental: re-clone only NodeInfos dirtied since the snapshot's
         generation; O(changes) not O(nodes) (cache.go:198's generation-ordered
         list, realized as a dirty set). A snapshot older than the dirty-set
-        horizon (e.g. a brand-new Snapshot) gets a full resync."""
+        horizon (e.g. a brand-new Snapshot) gets a full resync. The update,
+        lock wait included, is one ``cache.snapshot`` span whose ``nodes``
+        attribute counts the NodeInfos re-cloned."""
+        with tracing.span("cache.snapshot") as sp:
+            cloned = self._update_snapshot(snapshot)
+            if sp is not None:
+                sp.attributes["nodes"] = cloned
+        return snapshot
+
+    def _update_snapshot(self, snapshot: Snapshot) -> int:
+        """update_snapshot's work; returns the number of NodeInfos cloned."""
+        cloned = 0
         with self._lock:
             max_gen = snapshot.generation
             changed = False
@@ -292,6 +304,7 @@ class Cache:
                     if prev is None or prev.node is not ni.node:
                         snapshot.node_object_version += 1
                     snapshot.node_info_map[name] = ni.clone()
+                    cloned += 1
                     snapshot.changed_names.add(name)
                     batch_changed.add(name)
                     max_gen = max(max_gen, ni.generation)
@@ -310,7 +323,7 @@ class Cache:
                 snapshot.refresh_lists(structural=structural,
                                        changed_names=batch_changed)
             snapshot.generation = max_gen
-        return snapshot
+        return cloned
 
     def _horizon(self) -> int:  # ktpu: locked
         """Oldest snapshot generation the dirty set can serve incrementally."""

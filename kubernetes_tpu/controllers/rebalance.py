@@ -211,6 +211,10 @@ class Rebalancer:
 
     # ------------------------------------------------------------ control
 
+    def due(self, now: float) -> bool:
+        """Whether the score interval has elapsed (a tick would score)."""
+        return now - self._last_score_at >= self.score_interval_s
+
     def maybe_run(self, now: Optional[float] = None) -> Dict[str, object]:
         """One control-loop tick (housekeeping cadence). Cheap unless the
         score interval elapsed AND the commit plane is idle."""
@@ -220,7 +224,7 @@ class Rebalancer:
         worker = getattr(self.sched, "commit_worker", None)
         if worker is not None and not worker.idle():
             return {"ran": False, "reason": "commit-plane-busy"}
-        if now - self._last_score_at < self.score_interval_s:
+        if not self.due(now):
             return {"ran": False, "reason": "interval"}
         self._last_score_at = now
         score = score_cluster(self.sched)

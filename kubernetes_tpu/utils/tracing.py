@@ -16,19 +16,34 @@ loadable into any OTLP-compatible viewer).
 The scheduler wraps its cycle phases (snapshot/filter/score on the
 sequential path; sync/encode/dispatch/commit on the batch path), giving the
 per-phase latency attribution the reference gets from utiltrace +
-APIServerTracing spans."""
+APIServerTracing spans.
+
+Two spans come from the runtime under the program. ``enable()`` installs a
+``gc.callbacks`` hook (``disable()`` removes it) that records every garbage
+collection as a ``runtime.gc`` span on the collecting thread, and runs the
+hooks registered with ``on_enable``: the device-telemetry layer registers
+its jax.monitoring compile listener there, which back-fills each XLA compile
+as an ``xla.compile`` span."""
 
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import os
 import threading
 import time
 import uuid
-from typing import List, Optional
+from collections import deque
+from typing import Callable, List, Optional
 
 _tracer: Optional["Tracer"] = None
+# the disabled span(): one shared, stateless context manager (no allocation)
+_NULL_CM = contextlib.nullcontext()
+# run at every enable(): installers of span sources outside this module
+_enable_hooks: List[Callable[[], None]] = []
+# start of the collection in progress (CPython runs one at a time)
+_gc_start_ns = 0
 
 
 class Span:
@@ -97,6 +112,10 @@ class Tracer:
     def __init__(self, exporter):
         self.exporter = exporter
         self._local = threading.local()
+        # finished spans recorded where the exporter must not be entered (a
+        # GC callback can interrupt the exporter while it holds its lock):
+        # exported ahead of the next ordinary span, or by flush()
+        self._deferred: deque = deque()
 
     def _stack(self) -> list:
         st = getattr(self._local, "stack", None)
@@ -133,38 +152,104 @@ class Tracer:
         finally:
             s.end = time.time_ns()
             stack.pop()
+            self._export(s)
+
+    def _finished(self, name: str, start_ns: int, end_ns: int,
+                  attributes: dict) -> Span:
+        """A span with explicit timestamps, parented under this thread's
+        active span."""
+        stack = self._stack()
+        if stack:
+            trace_id, parent_id = stack[-1].trace_id, stack[-1].span_id
+        else:
+            trace_id, parent_id = uuid.uuid4().hex, None
+        s = Span(name, trace_id, parent_id, attributes)
+        s.start = int(start_ns)
+        s.end = int(end_ns)
+        return s
+
+    def _export(self, s: Span) -> None:
+        self.flush()
+        try:
+            self.exporter.export(s)
+        except Exception:  # noqa: BLE001 — tracing must never fail the
+            pass           # operation it instruments (a full disk would
+                           # otherwise read as device death upstream)
+
+    def flush(self) -> None:
+        """Export the deferred spans."""
+        while True:
+            try:
+                s = self._deferred.popleft()
+            except IndexError:
+                return
             try:
                 self.exporter.export(s)
-            except Exception:  # noqa: BLE001 — tracing must never fail the
-                pass           # operation it instruments (a full disk would
-                               # otherwise read as device death upstream)
+            except Exception:  # noqa: BLE001 — same never-fail rule
+                pass
 
 
 def enable(exporter=None) -> "Tracer":
-    """Install the process tracer (None exporter = in-memory)."""
+    """Install the process tracer (None exporter = in-memory), the GC hook,
+    and whatever the ``on_enable`` hooks install."""
     global _tracer
     _tracer = Tracer(exporter or InMemoryExporter())
+    if gc_callback not in gc.callbacks:
+        gc.callbacks.append(gc_callback)
+    for hook in list(_enable_hooks):
+        hook()
     return _tracer
 
 
 def disable() -> None:
     global _tracer
-    _tracer = None
+    t, _tracer = _tracer, None
+    if gc_callback in gc.callbacks:
+        gc.callbacks.remove(gc_callback)
+    if t is not None:
+        t.flush()
+
+
+def on_enable(hook: Callable[[], None]) -> None:
+    """Run ``hook`` at every ``enable()``, and now if a tracer is already
+    on: how a layer this module must not import installs a span source (the
+    device-telemetry compile listener behind ``xla.compile``)."""
+    _enable_hooks.append(hook)
+    if _tracer is not None:
+        hook()
+
+
+def gc_callback(phase: str, info: dict) -> None:
+    """The ``gc.callbacks`` hook ``enable()`` installs: one ``runtime.gc``
+    span per collection, parented under the collecting thread's active span.
+    A collection can start anywhere, inside the exporter's lock included, so
+    the span is deferred to the next ordinary export."""
+    global _gc_start_ns
+    if phase == "start":
+        _gc_start_ns = time.time_ns()
+        return
+    t, start = _tracer, _gc_start_ns
+    _gc_start_ns = 0
+    if t is None or not start:
+        return
+    try:
+        emit_deferred("runtime.gc", start, time.time_ns(),
+                      generation=info.get("generation"),
+                      collected=info.get("collected"))
+    except Exception:  # noqa: BLE001 — a GC callback must never raise
+        pass
 
 
 def get() -> Optional[Tracer]:
     return _tracer
 
 
-@contextlib.contextmanager
 def span(name: str, **attributes):
     """No-op when tracing is disabled (one global read)."""
     t = _tracer
     if t is None:
-        yield None
-    else:
-        with t.span(name, **attributes) as s:
-            yield s
+        return _NULL_CM
+    return t.span(name, **attributes)
 
 
 def current() -> Optional[Span]:
@@ -198,18 +283,18 @@ def emit(name: str, start_ns: int, end_ns: int, **attributes) -> None:
     t = _tracer
     if t is None:
         return
-    stack = t._stack()
-    if stack:
-        trace_id, parent_id = stack[-1].trace_id, stack[-1].span_id
-    else:
-        trace_id, parent_id = uuid.uuid4().hex, None
-    s = Span(name, trace_id, parent_id, attributes)
-    s.start = int(start_ns)
-    s.end = int(end_ns)
-    try:
-        t.exporter.export(s)
-    except Exception:  # noqa: BLE001 — same never-fail rule as _run_span
-        pass
+    t._export(t._finished(name, start_ns, end_ns, attributes))
+
+
+def emit_deferred(name: str, start_ns: int, end_ns: int,
+                  **attributes) -> None:
+    """``emit`` for a caller that may have interrupted the exporter (the GC
+    hook): the span is queued, not exported, until the next ordinary export
+    or ``disable()``."""
+    t = _tracer
+    if t is None:
+        return
+    t._deferred.append(t._finished(name, start_ns, end_ns, attributes))
 
 
 def format_traceparent() -> Optional[str]:

@@ -309,9 +309,10 @@ def emitted_span_names(pkg: str = None):
                 arg = node.args[0]
             elif node.func.attr == "span_from_remote" and len(node.args) >= 2:
                 arg = node.args[1]
-            elif node.func.attr == "emit" and node.args:
+            elif node.func.attr in ("emit", "emit_deferred") and node.args:
                 # tracing.emit(name, start_ns, end_ns): explicit-timestamp
-                # finished-span export (dispatch-profiler child spans)
+                # finished-span export (dispatch-profiler child spans, and
+                # the deferred GC spans)
                 arg = node.args[0]
             if arg is None:
                 continue
