@@ -51,6 +51,13 @@ def _tb_dict(tb: TopoBatch) -> dict:
     return {f.name: getattr(tb, f.name) for f in dataclasses.fields(tb)}
 
 
+def domain_axis(value_words: int, vd_override: Optional[int]) -> int:
+    """The general topology mode's domain-axis size Vd: the involved keys'
+    vocab bucket when the caller computed it, else the full per-key vocab
+    padding (``value_words`` = ExprTable.bits' 32-bit words per key)."""
+    return vd_override if vd_override else int(value_words) * 32
+
+
 def pallas_mode(nt: NodeTensors, axis_name, topo_enabled: bool) -> Optional[str]:
     """'compiled' | 'interpret' | None. KTPU_PALLAS=0 disables, =interpret
     forces the interpreter lowering (CPU tests of the kernel path). Read
@@ -343,8 +350,8 @@ def _speculative_core(pb, nt, weights, static_ok, static_ff, taint_raw,
     (the committing pod carries a term that interacts with this pod —
     rare: intra-batch anti-affinity/symmetric-score coupling) is
     conservatively DEFERRED to the next round, where the committed tables
-    are ground truth. Keys: tb dict, affinity_ok, vd, dom_t [T, N],
-    label_val [N, L], valid [N]."""
+    are ground truth. Keys: tb dict, affinity_ok, dom_t [T, N],
+    label_val [N, L], valid [N]; the domain axis Vd is seg0's."""
     P = pb.capacity
     N = nt.capacity  # LOCAL shard size under shard_map
     alloc = nt.allocatable
@@ -471,7 +478,7 @@ def _speculative_core(pb, nt, weights, static_ok, static_ff, taint_raw,
         hk_f = hostkey_ok.astype(jnp.int32)                     # [N]
     if gen is not None:
         tbx, affinity_ok = gen["tb"], gen["affinity_ok"]
-        vd = gen["vd"]
+        vd = seg0.shape[1]             # domain axis of the [T, Vd] table
         dom_t = gen["dom_t"]                                    # [T, N]
         label_val = gen["label_val"]                            # [N, L]
         valid_n = gen["valid"]                                  # [N]
@@ -482,15 +489,23 @@ def _speculative_core(pb, nt, weights, static_ok, static_ff, taint_raw,
             # [P, C, N]: domain id of node n under each constraint's key
             return label_val.T[keys]                            # gather rows
 
+        # domain ids depend only on each constraint's key and the node
+        # labels, which cannot change inside a batch: read them once here,
+        # outside the rounds, not twice per round in assemble()
+        dom_sf = _dom_of(tbx["sf_key"])                         # [P, C, N]
+        dom_ia = _dom_of(tbx["ia_key"])                         # [P, A, N]
+        dom_an = _dom_of(tbx["ianti_key"])
+        dom_ss = _dom_of(tbx["ss_key"])
+        dom_ip = _dom_of(tbx["ip_key"])
+
         def _seg_pc(values, dom):
             """[P, C, N] values segment-summed by [P, C, N] domain ids →
-            [P, C, Vd] (the per-pod batched _seg_sum). Under shard_map the
-            node axis is a local slice, so the per-domain sums psum across
-            shards — the result is the GLOBAL domain table, replicated."""
-            seg = jax.vmap(jax.vmap(
-                lambda v, d: jax.ops.segment_sum(v, d, num_segments=vd)))(
-                    values, dom)
-            return _gsum(seg, axis_name)
+            [P, C, Vd]: topology.seg_sum, the one helper the scan shares
+            (one-hot reduce for compact domains, scatter past
+            topology.ONEHOT_MAX_VD). Under shard_map the node axis is a
+            local slice, so the per-domain sums psum across shards — the
+            result is the GLOBAL domain table, replicated."""
+            return topology.seg_sum(values, dom, vd, axis_name)
 
 
     def topo_eval(sel_view, term_view, rival, active):
@@ -605,7 +620,6 @@ def _speculative_core(pb, nt, weights, static_ok, static_ff, taint_raw,
         sel_base, sel_d = sel_view
 
         # ---- spread filter (topology.spread_filter)
-        dom_sf = _dom_of(tbx["sf_key"])                          # [P, C, N]
         has_key = dom_sf > 0
         has_all = jnp.all(jnp.where(tbx["sf_valid"][:, :, None], has_key, True),
                           axis=1)                                # [P, N]
@@ -621,20 +635,19 @@ def _speculative_core(pb, nt, weights, static_ok, static_ff, taint_raw,
         ndom = jnp.sum(pres.astype(jnp.int32), axis=2)
         minm = jnp.where((tbx["sf_min_domains"] >= 0)
                          & (ndom < tbx["sf_min_domains"]), 0, minm)
-        cnt_at = jnp.take_along_axis(seg, dom_sf, axis=2)        # [P, C, N]
+        cnt_at = topology.seg_read(seg, dom_sf)                  # [P, C, N]
         ok_c = has_key & (cnt_at + tbx["sf_self"][:, :, None].astype(jnp.int32)
                           - minm[:, :, None] <= tbx["sf_skew"][:, :, None])
         spread_ok = jnp.all(
             jnp.where(tbx["sf_valid"][:, :, None], ok_c, True), axis=1)
 
         # ---- IPA filter checks 1+2 (topology.ipa_filter)
-        dom_ia = _dom_of(tbx["ia_key"])
         ia_has_key = dom_ia > 0
         ia_valid = tbx["ia_valid"]
         cnts_ia = _mix_gather(sel_base, sel_d, tbx["ia_sig"], rival)
         add_ia = jnp.where(valid_n[None, None, :] & ia_has_key, cnts_ia, 0)
         seg_ia = _seg_pc(add_ia, dom_ia)                         # [P, A, Vd]
-        cnt_at_ia = jnp.take_along_axis(seg_ia, dom_ia, axis=2)
+        cnt_at_ia = topology.seg_read(seg_ia, dom_ia)
         exist = cnt_at_ia > 0
         pods_exist = jnp.all(jnp.where(ia_valid[:, :, None], exist, True), axis=1)
         all_keys = jnp.all(jnp.where(ia_valid[:, :, None], ia_has_key, True),
@@ -645,12 +658,11 @@ def _speculative_core(pb, nt, weights, static_ok, static_ff, taint_raw,
         aff_ok = (~has_terms[:, None]) | (
             all_keys & (pods_exist | first_ok[:, None]))
 
-        dom_an = _dom_of(tbx["ianti_key"])
         an_has_key = dom_an > 0
         cnts_an = _mix_gather(sel_base, sel_d, tbx["ianti_sig"], rival)
         add_an = jnp.where(valid_n[None, None, :] & an_has_key, cnts_an, 0)
         seg_an = _seg_pc(add_an, dom_an)
-        an_cnt = jnp.take_along_axis(seg_an, dom_an, axis=2)
+        an_cnt = topology.seg_read(seg_an, dom_an)
         viol = jnp.any(tbx["ianti_valid"][:, :, None] & an_has_key
                        & (an_cnt > 0), axis=1)
         anti_ok = ~viol
@@ -658,7 +670,7 @@ def _speculative_core(pb, nt, weights, static_ok, static_ff, taint_raw,
         # ---- IPA check 3 against the ROUND-START seg_exist (deferral
         # covers the divergence window)
         exist_at = jnp.where(dom_t > 0,
-                             jnp.take_along_axis(seg_base, dom_t, axis=1), 0)  # [T,N]
+                             topology.seg_read(seg_base, dom_t), 0)  # [T, N]
         m = tbx["term_filter_match"].astype(jnp.int32)           # [P, T]
         viol_cnt = m @ exist_at
         exist_ok = viol_cnt == 0
@@ -672,7 +684,6 @@ def _speculative_core(pb, nt, weights, static_ok, static_ff, taint_raw,
         sel_base, sel_d = sel_view
 
         # spread score
-        dom_ss = _dom_of(tbx["ss_key"])                          # [P, C, N]
         has_key = dom_ss > 0
         ss_valid = tbx["ss_valid"]
         has_all = jnp.all(jnp.where(ss_valid[:, :, None], has_key, True), axis=1)
@@ -691,7 +702,7 @@ def _speculative_core(pb, nt, weights, static_ok, static_ff, taint_raw,
         cnts = _mix_gather(sel_base, sel_d, tbx["ss_sig"], rival)
         add = jnp.where(elig[:, None, :] & has_key, cnts, 0)
         seg = _seg_pc(add, dom_ss)
-        cnt_at = jnp.take_along_axis(seg, dom_ss, axis=2)
+        cnt_at = topology.seg_read(seg, dom_ss)
         cnt = jnp.where(tbx["ss_hostname"][:, :, None], cnts, cnt_at) \
             .astype(jnp.float32)
         contrib = jnp.where(
@@ -704,12 +715,11 @@ def _speculative_core(pb, nt, weights, static_ok, static_ff, taint_raw,
             raw, base_mask, ignored, jnp.any(ss_valid, axis=1)[:, None])
 
         # IPA score
-        dom_ip = _dom_of(tbx["ip_key"])
         ip_has_key = dom_ip > 0
         cnts_ip = _mix_gather(sel_base, sel_d, tbx["ip_sig"], rival)
         add_ip = jnp.where(valid_n[None, None, :] & ip_has_key, cnts_ip, 0)
         seg_ip = _seg_pc(add_ip, dom_ip)
-        cnt_at_ip = jnp.take_along_axis(seg_ip, dom_ip, axis=2).astype(jnp.float32)
+        cnt_at_ip = topology.seg_read(seg_ip, dom_ip).astype(jnp.float32)
         pref = jnp.sum(
             jnp.where(tbx["ip_valid"][:, :, None] & ip_has_key,
                       tbx["ip_w"][:, :, None].astype(jnp.float32) * cnt_at_ip,
@@ -789,8 +799,11 @@ def _speculative_core(pb, nt, weights, static_ok, static_ff, taint_raw,
         # winner check run on the owner shard; the accepted vector is then
         # made globally consistent)
         contender = active & any_f
-        win = jnp.full((N,), P, jnp.int32).at[local_choice].min(
-            jnp.where(contender & mine, iota_p, P))
+        # per-node min over a [P, N] one-hot, not a scatter-min: no scatter
+        # runs inside the rounds (TPU scatters serialise over their updates)
+        picks = ((iota_n[None, :] == local_choice[:, None])
+                 & (contender & mine)[:, None])                  # [P, N]
+        win = jnp.min(jnp.where(picks, iota_p[:, None], P), axis=0)
         accepted = _gany_pods(contender & mine & (win[local_choice] == iota_p))
 
         # ---- exact stability: rebuild each winner i's SEQUENTIAL view.
@@ -908,16 +921,17 @@ def _speculative_core(pb, nt, weights, static_ok, static_ff, taint_raw,
             onehot_i = onehot.astype(jnp.int32)
             sel_dyn = sel_dyn + jnp.einsum("ps,pn->sn", sig_mask_f, onehot_i)
             # seg_exist: each finalized pod's terms land at its node's
-            # domains (topology.commit_update's dom_col scatter, batched)
-            T = dom_t.shape[0]
+            # domains (topology.commit_update's one-hot add, batched over
+            # the round's pods: a segment sum over P)
             # the [T, Vd] seg table is REPLICATED: every shard must apply
-            # the identical scatter. Reuse the deferral block's dcol — its
-            # inputs (local_choice, mine) are unchanged, and recomputing
-            # would pay the [T, P] gather + cross-shard psum twice per round
+            # the identical add (dcol is already global, so no psum).
+            # Reuse the deferral block's dcol — its inputs (local_choice,
+            # mine) are unchanged, and recomputing would pay the [T, P]
+            # gather + cross-shard psum twice per round
             add_f = (term_mask_f.T * (dcol > 0)
                      * accepted[None, :].astype(jnp.int32))      # [T, P]
-            t_iota = jnp.arange(T, dtype=jnp.int32)[:, None]
-            term_dyn = term_dyn.at[t_iota, dcol].add(add_f)
+            term_dyn = term_dyn + topology.seg_sum(
+                add_f, dcol, term_dyn.shape[1])
         final = accepted | failing
         out_idx = jnp.where(accepted, choice, out_idx)
         best_sel = _gpick(
@@ -1084,9 +1098,7 @@ def schedule_batch_core(
     total_nodes = jnp.maximum(_gsum(jnp.sum(nt.valid), axis_name), 1)
     image_score = scores.score_image_locality(pb, nt, total_nodes=total_nodes)
 
-    # value-id domain capacity: the involved keys' vocab size when the
-    # caller computed it, else the full per-key vocab padding
-    vd = vd_override if vd_override else int(et.bits.shape[1]) * 32
+    vd = domain_axis(et.bits.shape[1], vd_override)
     if topo_mode == "general":
         topo_static = topology.make_static(
             tc.term_counts, tc.term_key, nt.label_val, nt.valid, vd, axis_name
@@ -1129,7 +1141,6 @@ def schedule_batch_core(
             gen_args = {
                 "tb": _tb_dict(tb),
                 "affinity_ok": static_masks["NodeAffinity"],
-                "vd": vd,
                 "dom_t": topo_static.dom_t,
                 "label_val": nt.label_val,
                 "valid": nt.valid,

@@ -36,8 +36,9 @@ from ..framework.plugins.quota import quota_precheck_status
 from ..framework.types import Diagnosis, QueuedPodInfo
 from ..metrics import latency_ledger
 from ..ops.encode import CapacityError
+from ..ops.topology import seg_mode
 from ..scheduler.scheduler import Scheduler
-from .batch import BatchResult, build_schedule_batch_fn
+from .batch import BatchResult, build_schedule_batch_fn, domain_axis
 from .device_state import DeviceState, caps_for_cluster
 from .errors import PermanentDeviceError
 
@@ -771,7 +772,13 @@ class TPUScheduler(Scheduler):
             # the packed block, zero extra dispatch
             quota_ns, quota_req = self._quota_batch_args(batched, device,
                                                          bucket)
-            with tracing.span("device.dispatch", topo=topo_mode):
+            span_attrs = {"topo": topo_mode}
+            if topo_mode == "general":
+                # the domain axis and the segment-sum form the program
+                # takes at it (topology.seg_mode, the same trace-time test)
+                vd = domain_axis(et.bits.shape[1], vd_bucket)
+                span_attrs.update(vd=vd, seg=seg_mode(vd))
+            with tracing.span("device.dispatch", **span_attrs):
                 result = self._run_batch_fn(
                     pb, et, device.nt, device.tc, tb, key,
                     adopt=True,

@@ -2,24 +2,29 @@
 PodTopologySpread and InterPodAffinity on the batched device path.
 
 Topology domains are label-value-id buckets: a constraint/term's per-domain
-pod counts are one scatter-add of TopoCounts rows over ``label_val[:, key]``,
-and per-node reads are one gather back — the tensorization of the reference's
-``map[{topologyKey,value}]int`` bookkeeping (podtopologyspread/filtering.go:40
-preFilterState, interpodaffinity/filtering.go:155 topologyToMatchedTermCount).
+pod counts are one segment sum of TopoCounts rows over ``label_val[:, key]``,
+and per-node reads are one read back (``seg_sum``/``seg_read``) — the
+tensorization of the reference's ``map[{topologyKey,value}]int`` bookkeeping
+(podtopologyspread/filtering.go:40 preFilterState,
+interpodaffinity/filtering.go:155 topologyToMatchedTermCount).
 
-Everything here runs INSIDE the commit scan of backend/batch.py: counts evolve
-as batch pods commit, so pod k sees exactly the topology state the reference's
-serial loop would (anti-affinity violations within one batch are impossible by
-construction, SURVEY.md §7 hard-part 4).
+These kernels serve both commit paths of backend/batch.py: the per-pod commit
+scan calls them one pod at a time, and the speculative rounds
+(``_speculative_core``) call ``seg_sum``/``seg_read`` batched over the pods of
+a round. Either way counts evolve as batch pods commit, so pod k sees exactly
+the topology state the reference's serial loop would (anti-affinity
+violations within one batch are impossible by construction, SURVEY.md §7
+hard-part 4).
 
-Sharding: scatters run over the local node shard, then one psum merges the
-per-shard segment tables; reads stay shard-local. seg_exist (existing-term
-domain counts) is replicated and updated on every shard via a psum'd
-commit-domain broadcast.
+Sharding: segment sums run over the local node shard, then one psum merges
+the per-shard segment tables; reads stay shard-local. seg_exist
+(existing-term domain counts) is replicated and updated on every shard via a
+psum'd commit-domain broadcast.
 """
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -53,29 +58,58 @@ class TopoStatic(NamedTuple):
 
 def make_static(term_counts: jax.Array, term_key: jax.Array, label_val: jax.Array,
                 valid: jax.Array, vd: int, axis_name: Optional[str] = None) -> TopoStatic:
-    T = term_counts.shape[0]
     dom_t = label_val[:, term_key].T                                  # [T, N]
     add = jnp.where(valid[None, :] & (dom_t > 0), term_counts, 0)
-    t_iota = jnp.arange(T, dtype=jnp.int32)[:, None]
-    seg = jnp.zeros((T, vd), jnp.int32).at[t_iota, dom_t].add(add)
-    return TopoStatic(dom_t=dom_t, seg_exist0=_gsum(seg, axis_name))
+    return TopoStatic(dom_t=dom_t, seg_exist0=seg_sum(add, dom_t, vd, axis_name))
 
 
-def _seg_sum(values: jax.Array, dom: jax.Array, vd: int, axis_name):
-    """[C, N] values segment-summed by domain id → [C, Vd] (psum'd global).
-    For compact domains the scatter becomes a one-hot contraction — TPU
-    scatters cost ~200µs of fixed overhead EACH inside the commit scan,
-    while the [C, N, Vd] one-hot matmul rides the MXU and fuses; counts stay
-    exact in f32 (< 2^24)."""
-    C = dom.shape[0]
-    if vd <= 256:
-        onehot = (dom[:, :, None] == jnp.arange(vd, dtype=dom.dtype)[None, None, :])
-        seg = jnp.einsum("cn,cnv->cv", values.astype(jnp.float32),
-                         onehot.astype(jnp.float32)).astype(jnp.int32)
+# Domain axes up to this size take the one-hot form of seg_sum/seg_read;
+# past it a [..., N, Vd] one-hot no longer pays and the scatter/gather stays.
+ONEHOT_MAX_VD = 256
+
+
+def seg_mode(vd: int) -> str:
+    """The form seg_sum/seg_read take at domain axis ``vd``: "onehot" or
+    "scatter" (a trace-time choice; the scheduler reports it on its
+    dispatch span)."""
+    return "onehot" if vd <= ONEHOT_MAX_VD else "scatter"
+
+
+def seg_sum(values: jax.Array, dom: jax.Array, vd: int, axis_name=None):
+    """[..., N] int32 values segment-summed by [..., N] domain ids →
+    [..., Vd] (psum'd global under shard_map). Ids outside [0, vd) are
+    dropped, as a scatter drops them.
+
+    For compact domains the sum is a compare-select-reduce against the
+    domain one-hot, which XLA fuses into one reduction (the one-hot is never
+    materialised): TPU scatters serialise over their updates and carry a
+    fixed overhead each, in the scan steps and the speculative rounds alike.
+    The sum stays in int32, so counts are exact for any int32 inputs — no
+    bf16 rounding of per-node counts above 256."""
+    if seg_mode(vd) == "onehot":
+        hit = dom[..., None] == jnp.arange(vd, dtype=dom.dtype)
+        seg = jnp.sum(jnp.where(hit, values[..., None], 0), axis=-2,
+                      dtype=values.dtype)
     else:
-        c_iota = jnp.arange(C, dtype=jnp.int32)[:, None]
-        seg = jnp.zeros((C, vd), jnp.int32).at[c_iota, dom].add(values)
+        lead, n = dom.shape[:-1], dom.shape[-1]
+        rows = math.prod(lead)
+        r_iota = jnp.arange(rows, dtype=jnp.int32)[:, None]
+        flat = jnp.broadcast_to(values, dom.shape).reshape(rows, n)
+        seg = jnp.zeros((rows, vd), values.dtype).at[
+            r_iota, dom.reshape(rows, n)].add(flat).reshape(*lead, vd)
     return _gsum(seg, axis_name)
+
+
+def seg_read(seg: jax.Array, dom: jax.Array) -> jax.Array:
+    """Per-node read back of a segment table: seg [..., Vd] at each node's
+    domain id dom [..., N] → [..., N] (seg's own dtype). Same one-hot rule
+    as seg_sum; an id outside [0, Vd) reads 0 in the one-hot form."""
+    vd = seg.shape[-1]
+    if seg_mode(vd) == "onehot":
+        hit = dom[..., None] == jnp.arange(vd, dtype=dom.dtype)
+        return jnp.sum(jnp.where(hit, seg[..., None, :], 0), axis=-1,
+                       dtype=seg.dtype)
+    return jnp.take_along_axis(seg, dom, axis=-1)
 
 
 def _seg_counts(sig: jax.Array, key: jax.Array, sel_counts: jax.Array,
@@ -92,8 +126,8 @@ def _seg_counts(sig: jax.Array, key: jax.Array, sel_counts: jax.Array,
     # them: tv == None). Keeps segment column 0 empty so whole-table sums
     # (the first-pod-in-cluster check) match the oracle.
     add = jnp.where(elig & has_key, cnts, 0)
-    seg = _seg_sum(add, dom, vd, axis_name)
-    cnt_at = jnp.take_along_axis(seg, dom, axis=1)                     # [C, N]
+    seg = seg_sum(add, dom, vd, axis_name)
+    cnt_at = seg_read(seg, dom)                                        # [C, N]
     return dom, has_key, seg, cnt_at
 
 
@@ -114,8 +148,8 @@ def spread_filter(xs, sel_counts, label_val, valid, affinity_ok, vd, axis_name):
     elig = valid & affinity_ok & has_all
     _, _, seg, cnt_at = _seg_counts(sf_sig, sf_key, sel_counts, label_val, elig, vd, axis_name)
 
-    pres = _seg_sum(jnp.broadcast_to(elig[None, :], dom.shape).astype(jnp.int32),
-                    dom, vd, axis_name) > 0                            # [C, Vd]
+    pres = seg_sum(jnp.broadcast_to(elig[None, :], dom.shape).astype(jnp.int32),
+                   dom, vd, axis_name) > 0                             # [C, Vd]
     minm = jnp.min(jnp.where(pres, seg, INT_MAX), axis=1)              # [C]
     any_pres = jnp.any(pres, axis=1)
     minm = jnp.where(any_pres, minm, 0)
@@ -149,7 +183,7 @@ def ipa_filter(xs, sel_counts, seg_exist, dom_t, label_val, valid, vd, axis_name
     anti_ok = ~viol
 
     # 3. existing pods' required anti-affinity vs the incoming pod
-    exist_at = jnp.where(dom_t > 0, jnp.take_along_axis(seg_exist, dom_t, axis=1), 0)  # [T, N]
+    exist_at = jnp.where(dom_t > 0, seg_read(seg_exist, dom_t), 0)     # [T, N]
     viol_cnt = jnp.einsum("t,tn->n", xs["term_filter_match"].astype(jnp.int32), exist_at)
     exist_ok = viol_cnt == 0
     return aff_ok, anti_ok, exist_ok, exist_at
@@ -174,8 +208,8 @@ def spread_score(xs, sel_counts, label_val, valid, affinity_ok, feasible, vd, ax
     base = feasible & ~ignored
 
     # domain sizes over filtered non-ignored nodes; hostname uses node count
-    pres = _seg_sum(jnp.broadcast_to(base[None, :], dom.shape).astype(jnp.int32),
-                    dom, vd, axis_name) > 0
+    pres = seg_sum(jnp.broadcast_to(base[None, :], dom.shape).astype(jnp.int32),
+                   dom, vd, axis_name) > 0
     sz = jnp.sum(pres, axis=1)                                          # [C]
     n_base = _gsum(jnp.sum(base.astype(jnp.int32)), axis_name)
     sz = jnp.where(ss_host, n_base, sz)
@@ -325,7 +359,7 @@ def commit_update_host(sel_counts, term_cnt, local_idx, commit, mine,
     """Hostname-mode commit: both tables are [*, N] and take a single-column
     add at the winning node — no domain broadcast needed (each shard owns
     its columns). One-hot elementwise instead of scatters (per-step scatter
-    overhead, see _seg_sum)."""
+    overhead, see seg_sum)."""
     col = ((jnp.arange(sel_counts.shape[1], dtype=jnp.int32) == local_idx)
            & commit & mine).astype(jnp.int32)                           # [N]
     sel_counts = sel_counts + pod_sig_mask.astype(jnp.int32)[:, None] * col[None, :]

@@ -132,16 +132,21 @@ class TestExactParity:
         assert len(bound) == 1
 
 
-def _run_sched(monkeypatch, flag, build_workload, n_nodes=12, batch=16):
+def _zone_node(i):
+    return (make_node(f"n{i}").capacity({"cpu": "8", "memory": "16Gi", "pods": 20})
+            .label("zone", f"z{i % 3}"))
+
+
+def _run_sched(monkeypatch, flag, build_workload, n_nodes=12, batch=16,
+               node=_zone_node, comparer=True):
     """Full TPUScheduler run under KTPU_SPEC=flag; returns {pod: node}."""
     monkeypatch.setenv("KTPU_SPEC", flag)
     monkeypatch.setenv("KTPU_PALLAS", "0")
     store = ClusterStore()
-    sched = TPUScheduler(store, batch_size=batch, comparer_every_n=1)
+    sched = TPUScheduler(store, batch_size=batch,
+                         comparer_every_n=1 if comparer else 0)
     for i in range(n_nodes):
-        store.create_node(
-            make_node(f"n{i}").capacity({"cpu": "8", "memory": "16Gi", "pods": 20})
-            .label("zone", f"z{i % 3}").obj())
+        store.create_node(node(i).obj())
     build_workload(store)
     sched.run_until_settled(max_no_progress=5)
     assert sched.comparer_mismatches == 0
@@ -341,6 +346,87 @@ class TestGeneralModeTopologyParity:
                 store.create_pod(pw.obj())
 
         self._check(monkeypatch, workload)
+
+
+ZONE_KEY = "topology.kubernetes.io/zone"
+
+
+def _topology_spreading_node(i):
+    """scheduler_perf's node-default.yaml node, zone-labelled as
+    TopologySpreading's labelNodePrepareStrategy does: node i in zone i % 3."""
+    return (make_node(f"n{i}").capacity({"cpu": "4", "memory": "32Gi", "pods": 110})
+            .label(ZONE_KEY, f"zone-{i % 3}"))
+
+
+def _topology_spreading_workload(n_pending, n_skewed):
+    """TopologySpreading's pods (100m/500Mi, zone DoNotSchedule, maxSkew 5,
+    selecting their own label) behind ``n_skewed`` already-bound pods of the
+    same label, all in zone-0, so the skew filter binds from the first pod."""
+    from kubernetes_tpu.api.types import LabelSelector
+
+    sel = LabelSelector(match_labels={"app": "spread-backlog"})
+
+    def pod(name):
+        return (make_pod(name).req({"cpu": "100m", "memory": "500Mi"})
+                .label("app", "spread-backlog")
+                .spread_constraint(5, ZONE_KEY, selector=sel))
+
+    def workload(store):
+        for i in range(n_skewed):
+            store.create_pod(pod(f"bound{i}").node(f"n{3 * i}").obj())
+        for i in range(n_pending):
+            store.create_pod(pod(f"p{i}").obj())
+
+    return workload
+
+
+class TestSpreadBacklogShape:
+    """The spread-backlog cell's constraint, scaled to seconds on the CPU:
+    3 zones on a node-default-shaped cluster, maxSkew 5."""
+
+    def test_zone_max_skew_5_parity(self, monkeypatch):
+        # The oracle comparer is off here: it re-runs the filters on the
+        # snapshot before the batch's bind tail lands, so it misses the
+        # batch's own earlier winners, and skew rejections are exactly what
+        # that view gets wrong. The skew rule is replayed below instead.
+        n_pending, n_skewed = 48, 9
+        workload = _topology_spreading_workload(n_pending, n_skewed)
+        a = _run_sched(monkeypatch, "0", workload, n_nodes=30, batch=16,
+                       node=_topology_spreading_node, comparer=False)
+        b = _run_sched(monkeypatch, "1", workload, n_nodes=30, batch=16,
+                       node=_topology_spreading_node, comparer=False)
+        assert a == b
+        # pods pop in creation order: each one's zone must satisfy
+        # count + 1 - min <= maxSkew on the zones its predecessors left
+        counts = [n_skewed, 0, 0]
+        for i in range(n_pending):
+            zone = int(a[f"p{i}"][1:]) % 3
+            assert counts[zone] + 1 - min(counts) <= 5, (i, counts, zone)
+            counts[zone] += 1
+        # nine pods start in zone-0: the filter keeps the first pods out
+        assert all(int(a[f"p{i}"][1:]) % 3 != 0 for i in range(4))
+
+    def test_dispatch_span_names_onehot_segment_sums(self, monkeypatch):
+        from kubernetes_tpu.utils import tracing
+
+        monkeypatch.setenv("KTPU_SPEC", "1")
+        monkeypatch.setenv("KTPU_PALLAS", "0")
+        tracer = tracing.enable()
+        try:
+            store = ClusterStore()
+            sched = TPUScheduler(store, batch_size=16)
+            for i in range(12):
+                store.create_node(_topology_spreading_node(i).obj())
+            _topology_spreading_workload(n_pending=20, n_skewed=3)(store)
+            sched.run_until_settled(max_no_progress=5)
+            spans = tracer.exporter.by_name("device.dispatch")
+        finally:
+            tracing.disable()
+        general = [s.attributes for s in spans
+                   if s.attributes.get("topo") == "general"]
+        assert general
+        for attrs in general:
+            assert attrs["vd"] == 64 and attrs["seg"] == "onehot"
 
 
 class TestEndToEndForcedSpec:

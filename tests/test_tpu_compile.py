@@ -135,6 +135,40 @@ def test_schedule_batch_compiles_at_headline_size(one_chip, headline_batch,
         assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_zone_spread_rounds_compile_at_128_pods(one_chip):
+    """The speculative rounds in the general topology mode at 5000 nodes x
+    128 pods over a 256-value domain axis, the largest the one-hot segment
+    sums take: the per-site [P, C, N, Vd] one-hot must stay fused, not
+    materialise (it would be 335 MB a site at this size)."""
+    from kubernetes_tpu.api.types import LabelSelector
+    from kubernetes_tpu.api.wrappers import make_node, make_pod
+    from kubernetes_tpu.backend.batch import schedule_batch
+    from kubernetes_tpu.backend.device_state import caps_for_cluster
+    from kubernetes_tpu.backend.sig_table import SigTable
+    from kubernetes_tpu.framework.types import NodeInfo
+    from kubernetes_tpu.ops.encode import ClusterEncoder
+
+    zone = "topology.kubernetes.io/zone"
+    enc = ClusterEncoder(caps_for_cluster(NODES, 128))
+    sig_table = SigTable(enc)
+    nt = enc.encode_snapshot([NodeInfo(
+        make_node(f"node-{i}").capacity(
+            {"cpu": "4", "memory": "32Gi", "pods": 110})
+        .label(zone, f"zone-{i}").obj()) for i in range(3)])
+    sel = LabelSelector(match_labels={"app": "spread"})
+    pods = [make_pod("p-0").req({"cpu": "100m", "memory": "500Mi"})
+            .label("app", "spread").spread_constraint(5, zone, selector=sel)
+            .obj()]
+    pb, et = enc.encode_pods(pods)
+    args = _shapes((pb, et, nt, sig_table.topo_counts(),
+                    sig_table.encode_topo(pods)), one_chip)
+    key = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = schedule_batch.lower(
+        *args, key, topo_mode="general", spec_decode=True,
+        vd_override=256, ports_enabled=False).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 28
+
+
 def test_sharded_schedule_batch_compiles_on_2x2_mesh(topo, headline_batch):
     """parallel/mesh.py's node-sharded program over the four described
     chips: node axis split four ways, winners reduced by collectives."""
