@@ -85,6 +85,11 @@ CRITICAL_PATH_SPANS = frozenset({
     "host.commit",
     "device.apply_deltas",    # wire: server half of the delta push
     "device.schedule_batch",  # wire: server half of the batch call
+    # wire: client half of each call (backend/wire.py), the server halves'
+    # parents: one span per ApplyDeltas part on the scheduling thread, one
+    # per ScheduleBatch on the thread that sends it
+    "wire.apply_deltas",
+    "wire.schedule_batch",
     # the scheduling loop's own work and the runtime under it: the wait on
     # the commit worker (or the inline commits) at a drain, the cache
     # snapshot update, the housekeeping sweep, and GC pauses and XLA

@@ -7,6 +7,14 @@ pod shapes, so the steady-state request is one template table + name refs
 instead of N full pod objects), and device-computed preemption hints
 riding back with unschedulable results.
 
+Message size: the server keeps gRPC's default 4 MiB receive limit, as a
+scheduler plugin on grpc-go's defaults keeps its own. The client cuts each
+ApplyDeltas request into bounded parts (backend/wire.py,
+MAX_DELTA_PART_BYTES = 1 MiB): a full sync of 5000 nodes goes as about five,
+the first carrying ``full_nodes`` so the service's ghost sweep runs once over
+the whole set. A request refused for its size is a permanent error, not
+retried.
+
 grpc service stubs are not generated (grpc_tools is absent from the image);
 the server registers generic method handlers and the client uses
 channel.unary_unary — functionally identical to protoc-gen-grpc output.
@@ -25,6 +33,7 @@ import os
 import threading
 from typing import Dict, Optional
 
+from . import wire
 from .errors import (
     ConflictError,
     PermanentDeviceError,
@@ -129,14 +138,26 @@ def pb2():
 # DeviceService and WireScheduler stay transport-agnostic)
 
 
+def _varint_size(n: int) -> int:
+    """Bytes of ``n`` as a protobuf varint."""
+    size = 1
+    while n >= 0x80:
+        n >>= 7
+        size += 1
+    return size
+
+
+def _node_delta(e: dict):
+    return pb2().NodeDelta(
+        node_json=json.dumps(e["node"]).encode(),
+        pod_json=[json.dumps(pw).encode() for pw in e.get("pods", ())],
+        gen=int(e.get("gen", 0)))
+
+
 def _deltas_to_proto(payload: dict):
     p = pb2()
     req = p.ApplyDeltasRequest(full=bool(payload.get("full")))
-    for e in payload.get("nodes", ()):
-        req.nodes.append(p.NodeDelta(
-            node_json=json.dumps(e["node"]).encode(),
-            pod_json=[json.dumps(pw).encode() for pw in e.get("pods", ())],
-            gen=int(e.get("gen", 0))))
+    req.nodes.extend(_node_delta(e) for e in payload.get("nodes", ()))
     req.removed.extend(payload.get("removed", ()))
     for ns, labels in (payload.get("namespaces") or {}).items():
         req.namespaces[ns] = json.dumps(labels).encode()
@@ -149,6 +170,8 @@ def _deltas_to_proto(payload: dict):
         req.inflight_batch_ids.extend(payload.get("inflightBatchIds") or ())
     if "replicator" in fields:
         req.replicator = bool(payload.get("replicator"))
+    if "full_nodes" in fields:
+        req.full_nodes.extend(payload.get("fullNodes") or ())
     _stamp_session_proto(req, payload)
     return req
 
@@ -192,6 +215,8 @@ def _deltas_from_proto(req) -> dict:
         out["inflightBatchIds"] = list(req.inflight_batch_ids)
     if "replicator" in fields and req.replicator:
         out["replicator"] = True
+    if "full_nodes" in fields and req.full_nodes:
+        out["fullNodes"] = list(req.full_nodes)
     out.update(_session_from_proto(req))
     return out
 
@@ -471,17 +496,22 @@ def serve_grpc(service, port: int = 0):
 class GrpcClient:
     """Drop-in for service.WireClient over gRPC: same dict payloads, same
     error taxonomy and retry policy. gRPC status codes map onto the
-    taxonomy: UNAVAILABLE/DEADLINE_EXCEEDED are transient,
-    FAILED_PRECONDITION is the stale-epoch signal, everything else is
-    permanent (a deterministic server exception re-raises on re-send)."""
+    taxonomy: UNAVAILABLE/DEADLINE_EXCEEDED and RESOURCE_EXHAUSTED are
+    transient, except a message-size rejection (the same bytes are refused
+    again), FAILED_PRECONDITION is the stale-epoch signal, everything else
+    is permanent (a deterministic server exception re-raises on re-send)."""
 
     _STALE_PREFIX = "stale epoch; current="
+    # grpc core's words for a message over a receive or send limit
+    _SIZE_REJECTION = "message larger than max"
 
     def __init__(self, endpoint: str, read_timeout: float = 60.0,
-                 retry: Optional[RetryPolicy] = None, fault_plan=None):
+                 retry: Optional[RetryPolicy] = None, fault_plan=None,
+                 on_request_bytes=None):
         import grpc
 
         p = pb2()
+        self.on_request_bytes = on_request_bytes  # see backend/wire.py
         self.read_timeout = read_timeout
         self.retry = retry if retry is not None else RetryPolicy()
         self.fault_plan = fault_plan
@@ -540,6 +570,10 @@ class GrpcClient:
                     # the typed conflict verdict: fenced session or a
                     # cross-client pod/capacity race — rejoin/requeue
                     raise ConflictError(details or "commit conflict") from e
+                if (code == grpc.StatusCode.RESOURCE_EXHAUSTED
+                        and self._SIZE_REJECTION in details):
+                    raise PermanentDeviceError(
+                        f"device service {code.name}: {details}") from e
                 if code in (grpc.StatusCode.UNAVAILABLE,
                             grpc.StatusCode.DEADLINE_EXCEEDED,
                             grpc.StatusCode.RESOURCE_EXHAUSTED):
@@ -557,17 +591,41 @@ class GrpcClient:
             out["sessionGen"] = int(resp.session_gen)
         return out
 
-    def apply_deltas(self, payload: dict) -> dict:
-        resp = self._call("apply_deltas", self._apply, _deltas_to_proto(payload))
+    # the encoding backend/wire.py cuts ApplyDeltas parts with
+    @staticmethod
+    def _encode_node_delta(entry: dict):
+        delta = _node_delta(entry)
+        n = delta.ByteSize()
+        # its share of a request: the field's tag, length prefix and body
+        return delta, 1 + _varint_size(n) + n
+
+    @staticmethod
+    def _deltas_request(head: dict, encoded):
+        req = _deltas_to_proto(head)
+        req.nodes.extend(encoded)
+        return req, req.ByteSize()
+
+    def _send_deltas(self, req) -> dict:
+        resp = self._call("apply_deltas", self._apply, req)
         out = {"nodes": resp.nodes}
         if resp.epoch:
             out["epoch"] = resp.epoch
             out["deltaSeq"] = resp.delta_seq
         return self._session_gen_out(resp, out)
 
+    @staticmethod
+    def _batch_request(payload: dict):
+        req = _batch_to_proto(payload)
+        return req, req.ByteSize()
+
+    def apply_deltas(self, payload: dict) -> dict:
+        return wire.apply_deltas(self, payload)
+
     def schedule_batch(self, payload: dict) -> dict:
-        resp = self._call("schedule_batch", self._schedule,
-                          _batch_to_proto(payload))
+        return wire.schedule_batch(self, payload)
+
+    def _send_batch(self, req) -> dict:
+        resp = self._call("schedule_batch", self._schedule, req)
         out = _results_from_proto(resp)
         if resp.epoch:
             out["epoch"] = resp.epoch

@@ -57,7 +57,7 @@ from ..ops.encode import CapacityError
 from ..scheduler.scheduler import Scheduler
 from ..testing import locktrace
 from ..utils import tracing
-from . import telemetry
+from . import telemetry, wire
 from .batch import build_schedule_batch_fn
 from .circuit import CircuitBreaker, OPEN, STATE_VALUES
 from .device_state import DeviceState, caps_for_cluster
@@ -458,14 +458,20 @@ class DeviceService:
                 s.last_push_seq = self.delta_seq
                 raise ConflictError(
                     "replicator lapped by a direct full resync; reseed")
+            full_set = 0
             if req.get("full"):
                 # full resync replaces THIS client's contribution only. A
                 # mirror node no other live session claims and the full set
                 # omits is a ghost (a dead predecessor's world) — sweep it.
                 # With a single session this degenerates to the old
-                # clear-everything semantics.
+                # clear-everything semantics. A full sync sent in parts
+                # names its whole set in the first part (``fullNodes``):
+                # the sweep runs once, here, and keeps the nodes the later
+                # parts bring.
                 s.sent_gens.clear()
                 pushed = {node.meta.name for node, _, _ in decoded}
+                pushed.update(req.get("fullNodes") or ())
+                full_set = len(pushed)
                 # the anonymous (legacy single-client) session never claims
                 # nodes: it predates sessions, sends no heartbeats, and its
                 # full pushes keep the old everything-or-nothing contract
@@ -595,7 +601,9 @@ class DeviceService:
                 self.quota_table = {
                     ns: (rows.get("used") or [], rows.get("limit") or [])
                     for ns, rows in qt.items()}
-            self._sync()
+            # a full sync sizes a new device for its whole set, so one sent
+            # in parts ends on the capacities one message would give
+            self._sync(min_nodes=full_set)
             self.delta_seq += 1
             s.delta_seq += 1
             s.last_push_seq = self.delta_seq
@@ -615,10 +623,10 @@ class DeviceService:
             if hold.node_name == name:
                 del self.holds[key]
 
-    def _ensure_device(self) -> None:  # ktpu: locked
+    def _ensure_device(self, min_nodes: int = 0) -> None:  # ktpu: locked
         import dataclasses
 
-        n = max(len(self.infos), 1)
+        n = max(len(self.infos), min_nodes, 1)
         ns_fn = lambda ns: self.ns_labels.get(ns, {})  # noqa: E731  # ktpu: unguarded-ok(invoked by device.sync, which only runs under the service lock)
         if self.device is None:
             self.device = DeviceState(caps_for_cluster(n, batch=self.batch_size),
@@ -633,8 +641,8 @@ class DeviceService:
                 value_words=max(caps.value_words, (nodes + 2 + 31) // 32)),
                 ns_labels_fn=ns_fn)
 
-    def _sync(self) -> None:  # ktpu: locked
-        self._ensure_device()
+    def _sync(self, min_nodes: int = 0) -> None:  # ktpu: locked
+        self._ensure_device(min_nodes)
         for _attempt in range(8):
             try:
                 # deliberate blocking-under-lock: the mirror the device syncs
@@ -1190,7 +1198,8 @@ class WireClient:
 
     def __init__(self, endpoint: str, connect_timeout: float = 5.0,
                  read_timeout: float = 60.0, retry: Optional[RetryPolicy] = None,
-                 fault_plan=None):
+                 fault_plan=None, on_request_bytes=None):
+        self.on_request_bytes = on_request_bytes  # see backend/wire.py
         self.endpoint = endpoint.rstrip("/")
         u = urllib.parse.urlsplit(self.endpoint)
         scheme = u.scheme or "http"
@@ -1264,8 +1273,9 @@ class WireClient:
         return out
 
     def _post(self, path: str, payload: dict, op: str) -> dict:
-        data = json.dumps(payload).encode()
+        return self._post_bytes(path, json.dumps(payload).encode(), op)
 
+    def _post_bytes(self, path: str, data: bytes, op: str) -> dict:
         def attempt():
             raise_injected_fault(self.fault_plan, op, self.read_timeout)
             return self._do_post(path, data)
@@ -1277,11 +1287,37 @@ class WireClient:
     supports_health = True
     supports_sessions = True
 
+    # the encoding backend/wire.py cuts ApplyDeltas parts with: a request
+    # body is the head's JSON with the entries' JSON spliced in as "nodes"
+    @staticmethod
+    def _encode_node_delta(entry: dict):
+        data = json.dumps(entry).encode()
+        return data, len(data) + 2  # with its ", " separator
+
+    @staticmethod
+    def _deltas_request(head: dict, encoded):
+        body = json.dumps(head).encode()
+        if encoded:
+            body = (body[:-1] + (b", " if len(body) > 2 else b"")
+                    + b'"nodes": [' + b", ".join(encoded) + b"]}")
+        return body, len(body)
+
+    def _send_deltas(self, body: bytes) -> dict:
+        return self._post_bytes("/v1/applyDeltas", body, "apply_deltas")
+
+    @staticmethod
+    def _batch_request(payload: dict):
+        body = json.dumps(payload).encode()
+        return body, len(body)
+
+    def _send_batch(self, body: bytes) -> dict:
+        return self._post_bytes("/v1/scheduleBatch", body, "schedule_batch")
+
     def apply_deltas(self, payload: dict) -> dict:
-        return self._post("/v1/applyDeltas", payload, "apply_deltas")
+        return wire.apply_deltas(self, payload)
 
     def schedule_batch(self, payload: dict) -> dict:
-        return self._post("/v1/scheduleBatch", payload, "schedule_batch")
+        return wire.schedule_batch(self, payload)
 
     def health(self) -> dict:
         """The cheap identity/liveness verb (half-open probe)."""
@@ -1471,19 +1507,25 @@ class WireScheduler(Scheduler):
             raise ValueError(
                 f"fault_plan list ({len(plans)}) must match endpoints "
                 f"({len(endpoints)})")
+
+        def count_bytes(op, nbytes):  # every transport client's requests
+            self.smetrics.wire_request_bytes.inc(op, value=float(nbytes))
+
         if transport == "grpc":
             from .grpc_service import GrpcClient
 
             def make_client(ep, plan, retry=None):
                 return GrpcClient(ep, read_timeout=read_timeout,
                                   retry=retry or self.retry_policy,
-                                  fault_plan=plan)
+                                  fault_plan=plan,
+                                  on_request_bytes=count_bytes)
         else:
             def make_client(ep, plan, retry=None):
                 return WireClient(ep, connect_timeout=connect_timeout,
                                   read_timeout=read_timeout,
                                   retry=retry or self.retry_policy,
-                                  fault_plan=plan)
+                                  fault_plan=plan,
+                                  on_request_bytes=count_bytes)
         if len(endpoints) > 1:
             from .fabric import DeviceFabric
 
@@ -1676,7 +1718,8 @@ class WireScheduler(Scheduler):
 
     def _push_deltas(self) -> None:
         """Incremental state sync. Bookkeeping (_sent_gens/_sent_ns) commits
-        only AFTER the wire call succeeds: a failed push must leave the rows
+        only AFTER the wire call succeeds, every part of it acknowledged
+        (backend/wire.py cuts a large push): a failed push must leave the rows
         marked unsent, or the retry after recovery would skip them and the
         device mirror would silently diverge from host truth."""
         self.cache.update_snapshot(self.snapshot)
@@ -1728,6 +1771,23 @@ class WireScheduler(Scheduler):
         if quota_table is not None:
             self._sent_quota = quota_table
 
+    def warm_buckets(self, sample_pods=None) -> int:
+        """Compile the service's batch program before the first batch, as
+        TPUScheduler.warm_buckets does in process: without it the first
+        batch pays the compile and the sizer's latency model collapses. A
+        state push, then one ScheduleBatch with no pods: the service pads
+        every batch to its pod capacity, so that is the program the real
+        batches run, for the topology the synced state registers.
+        ``sample_pods`` are not sent (the service would place them).
+        Returns the number of programs warmed: 0 when the service cannot be
+        reached, which the breaker then handles on the first batch."""
+        try:
+            self._push_deltas()
+            self._send_batch_payload(self._build_batch_payload([]))
+        except DeviceServiceError:
+            return 0
+        return 1
+
     def _wire_quota_table(self) -> Optional[Dict[str, dict]]:
         """The COMPLETE quota-ledger export for the device screen when it
         changed since the last acknowledged push, else None. Shipped whole
@@ -1747,7 +1807,10 @@ class WireScheduler(Scheduler):
     def _full_resync(self, new_epoch: Optional[str] = None) -> None:
         """Epoch-mismatch recovery: forget everything we believe the device
         holds and ship the complete host truth as one ``full`` delta (the
-        informer relist of the crash-only contract, pointed at the device)."""
+        informer relist of the crash-only contract, pointed at the device),
+        sent in bounded parts when it is large (backend/wire.py). A failure
+        on any part leaves nothing marked as sent and the epoch unknown, so
+        the next push is a full sync again."""
         self.resyncs += 1
         self._wire_sync_era += 1
         self._sent_gens.clear()
@@ -1774,7 +1837,11 @@ class WireScheduler(Scheduler):
         tp = tracing.format_traceparent()
         if tp:
             payload["traceparent"] = tp
-        out = self.client.apply_deltas(payload)
+        try:
+            out = self.client.apply_deltas(payload)
+        except DeviceServiceError:
+            self._device_epoch = None
+            raise
         self._device_epoch = out.get("epoch", new_epoch)
         self._session_gen = out.get("sessionGen", self._session_gen)
         self._sent_gens.update(pending_gens)

@@ -143,12 +143,18 @@ class SchedulerMetrics:
             ["result"],  # scheduled|rejected
         ))
         # fault-tolerant wire path (backend/service.py): transport retries,
-        # breaker state (0 closed, 1 half-open, 2 open), and cumulative time
-        # spent scheduling through the sequential oracle because the device
+        # the bytes of each request sent (backend/wire.py), breaker state
+        # (0 closed, 1 half-open, 2 open), and cumulative time spent
+        # scheduling through the sequential oracle because the device
         # service was unavailable
         self.wire_retries = r.register(Counter(
             "scheduler_wire_retries_total",
             "Device-service transport retries by operation.",
+            ["op"],
+        ))
+        self.wire_request_bytes = r.register(Counter(
+            "scheduler_wire_request_bytes_total",
+            "Serialized bytes of device-service requests sent, by operation.",
             ["op"],
         ))
         self.backend_circuit_state = r.register(Gauge(
