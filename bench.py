@@ -99,6 +99,9 @@ CRITICAL_PATH_SPANS = frozenset({
     "scheduling.housekeeping",
     "runtime.gc",
     "xla.compile",
+    # once, at the end of warm-up: the collect and freeze that take the
+    # warm heap out of the collector (utils/heap.py)
+    "runtime.gc_freeze",
 })
 
 

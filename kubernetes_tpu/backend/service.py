@@ -56,7 +56,7 @@ from ..metrics import latency_ledger
 from ..ops.encode import CapacityError
 from ..scheduler.scheduler import Scheduler
 from ..testing import locktrace
-from ..utils import tracing
+from ..utils import heap, tracing
 from . import telemetry, wire
 from .batch import build_schedule_batch_fn
 from .circuit import CircuitBreaker, OPEN, STATE_VALUES
@@ -1780,12 +1780,15 @@ class WireScheduler(Scheduler):
         batches run, for the topology the synced state registers.
         ``sample_pods`` are not sent (the service would place them).
         Returns the number of programs warmed: 0 when the service cannot be
-        reached, which the breaker then handles on the first batch."""
+        reached, which the breaker then handles on the first batch. Once
+        warm, the heap leaves the collector (utils/heap.py); with the service
+        in this process, that covers both."""
         try:
             self._push_deltas()
             self._send_batch_payload(self._build_batch_payload([]))
         except DeviceServiceError:
             return 0
+        heap.settle_warm_heap(self.smetrics)
         return 1
 
     def _wire_quota_table(self) -> Optional[Dict[str, dict]]:

@@ -38,6 +38,7 @@ from ..metrics import latency_ledger
 from ..ops.encode import CapacityError
 from ..ops.topology import seg_mode
 from ..scheduler.scheduler import Scheduler
+from ..utils import heap
 from .batch import BatchResult, build_schedule_batch_fn, domain_axis
 from .device_state import DeviceState, caps_for_cluster
 from .errors import PermanentDeviceError
@@ -1826,7 +1827,10 @@ class TPUScheduler(Scheduler):
         # measured-phase delta is taken after this), storms are not
         # flagged — a warmup sweep is not a mid-run bucket walk
         with telemetry.calibration():
-            return self._warm_buckets_inner(sample_pods)
+            warmed = self._warm_buckets_inner(sample_pods)
+        # the program is warm: take its heap out of the collector
+        heap.settle_warm_heap(self.smetrics)
+        return warmed
 
     def _warm_buckets_inner(self, sample_pods=None) -> int:
         from ..api.wrappers import make_pod

@@ -254,6 +254,12 @@ class SchedulerMetrics:
             "packed result block).",
             ["direction"],
         ))
+        # the Python runtime under the program (utils/heap.py): objects
+        # moved out of the collector's reach once the program was warm
+        self.gc_frozen_objects = r.register(Gauge(
+            "scheduler_gc_frozen_objects",
+            "Objects frozen out of the garbage collector after warm-up.",
+        ))
         self.flight_events = r.register(Counter(
             "scheduler_flight_recorder_events_total",
             "Batch flight-recorder events by type.",

@@ -5,6 +5,9 @@ xla_force_host_platform_device_count=8 per the driver's dryrun contract.
 """
 
 import os
+import sys
+
+import pytest
 
 # Unconditional: on a machine with a chip JAX would pick the TPU, but the
 # test contract is an 8-device virtual CPU mesh.
@@ -24,3 +27,15 @@ try:
     jax.config.update("jax_platforms", _platform)
 except Exception:  # noqa: BLE001 — jax absent: nothing to force
     pass
+
+
+@pytest.fixture(autouse=True)
+def _restore_heap_policy():
+    """A test that warms a scheduler freezes the process's heap and raises
+    the young collector threshold (utils/heap.py): put both back after it,
+    so the policy never reaches the tests that run after it in this
+    process."""
+    yield
+    heap = sys.modules.get("kubernetes_tpu.utils.heap")
+    if heap is not None and heap.settled():
+        heap.restore()

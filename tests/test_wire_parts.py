@@ -3,7 +3,10 @@ part goes through a gRPC server with its default message limits in several
 parts, each under the bound, and leaves the mirror a one-message sync
 leaves; the ghost sweep runs once; a failure partway leaves nothing marked
 as sent; a message-size rejection is not retried; the client's wire spans
-parent the service's; the wire warm-up compiles before the first batch."""
+parent the service's; the wire warm-up compiles before the first batch and
+then settles the heap."""
+
+import gc
 
 import grpc
 import pytest
@@ -18,7 +21,7 @@ from kubernetes_tpu.backend.grpc_service import (GrpcClient, pb2,
                                                  pb2_available, serve_grpc)
 from kubernetes_tpu.backend.service import (DeviceService, WireClient,
                                             WireScheduler, serve)
-from kubernetes_tpu.utils import tracing
+from kubernetes_tpu.utils import heap, tracing
 
 pytestmark = pytest.mark.skipif(not pb2_available(),
                                 reason="no protobuf module for the gRPC binding")
@@ -296,3 +299,17 @@ def test_warm_buckets_compiles_before_the_first_batch(grpc_service):
                          batch_size=8, connect_timeout=0.2,
                          wire_max_retries=0)
     assert dead.warm_buckets() == 0
+
+
+def test_warm_buckets_settles_the_heap(grpc_service):
+    """Once the wire warm-up has reached the service, the process's warm
+    heap leaves the collector (utils/heap.py), as after
+    TPUScheduler.warm_buckets: client and service share this process."""
+    _svc, port = grpc_service
+    sched = _wire_scheduler(_cluster(40, 10), port)
+    assert not heap.settled()
+    assert sched.warm_buckets() == 1
+    assert heap.settled() and gc.get_freeze_count() > 0
+    assert gc.get_threshold()[0] == heap.YOUNG_THRESHOLD
+    assert sched.smetrics.gc_frozen_objects.labels() > 0
+    sched.client.close()
