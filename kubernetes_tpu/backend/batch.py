@@ -162,6 +162,23 @@ DEFAULT_WEIGHTS = {
 }
 
 
+# The first-fail ids the program writes: id k names entry k-1, in filter
+# config order (default_plugins.go); 0 = passes. Both drivers read them here.
+ATTRIBUTION_ORDER = (
+    ("NodeUnschedulable", "node(s) were unschedulable"),
+    ("NodeName", "node(s) didn't match the requested node name"),
+    ("TaintToleration", "node(s) had untolerated taint"),
+    ("NodeAffinity", "node(s) didn't match Pod's node affinity/selector"),
+    ("NodePorts", "node(s) didn't have free ports for the requested pod ports"),
+    ("NodeResourcesFit", "Insufficient resources"),
+    ("PodTopologySpread", "node(s) didn't match pod topology spread constraints"),
+    ("InterPodAffinity", "node(s) didn't match pod affinity/anti-affinity rules"),
+    ("VolumeBinding", "node(s) didn't satisfy volume placement"),
+    ("DynamicResources", "cannot allocate all claims"),
+    ("SlicePacking", "node(s) outside the gang's planned torus slice"),
+)
+
+
 class BatchResult(NamedTuple):
     node_idx: jax.Array      # [P] int32 chosen GLOBAL slot, -1 = unschedulable
     best_score: jax.Array    # [P] float32
@@ -172,7 +189,7 @@ class BatchResult(NamedTuple):
     spread_ok: jax.Array     # [P, N] PodTopologySpread filter at decision time
     ipa_ok: jax.Array        # [P, N] InterPodAffinity (all three checks)
     # [P, N] int8: 0 = feasible, else 1-based index into the filter config
-    # order (tpu_scheduler._ATTRIBUTION_ORDER) of the first failing plugin.
+    # order (ATTRIBUTION_ORDER) of the first failing plugin.
     # Diagnosis on the host is ONE device→host read of this array instead of
     # eight mask reads — each read is a blocking device read.
     first_fail: jax.Array
@@ -1080,7 +1097,7 @@ def schedule_batch_core(
         static_ok = static_ok & slice_mask
 
     # static half of the first-failing-plugin table (ids follow the filter
-    # config order in tpu_scheduler._ATTRIBUTION_ORDER; 0 = passes). Reverse
+    # config order in ATTRIBUTION_ORDER; 0 = passes). Reverse
     # assignment order makes the earliest failing plugin win.
     static_ff = jnp.zeros(static_ok.shape, jnp.int8)
     if slice_mask is not None:
@@ -1550,6 +1567,14 @@ def spec_decode_eligible(sample_k) -> bool:
     return True
 
 
+def bucket_sig(capacity, topo_mode: Optional[str], topo_enabled: bool) -> str:
+    """The batch program's bucket signature: padded pod capacity + topology
+    mode, the two shape axes the sizer and the topology walk retrace over.
+    The compile, dispatch and cost ledgers and the flight recorder key on
+    it."""
+    return f"{capacity}/{topo_mode or ('general' if topo_enabled else 'off')}"
+
+
 def build_schedule_batch_fn(weights: Dict[str, float] = None):
     """Bind plugin weights statically; returns
     fn(pb, et, nt, tc, tb, key, topo_enabled=True, topo_carry=None,
@@ -1587,10 +1612,9 @@ def build_schedule_batch_fn(weights: Dict[str, float] = None):
         if telemetry.get() is not None:
             # cost ledger: AOT-lower the exact signature just dispatched and
             # keep its flops/bytes once per (program, bucket sig) — this is
-            # the one place the batch program's full kwargs exist. Sig
-            # mirrors _run_batch_fn's compile-ledger bucket.
-            sig = (f"{getattr(pb, 'capacity', '?')}/"
-                   f"{topo_mode or ('general' if topo_enabled else 'off')}")
+            # the one place the batch program's full kwargs exist
+            sig = bucket_sig(getattr(pb, "capacity", "?"), topo_mode,
+                             topo_enabled)
             telemetry.cost_probe("schedule_batch", sig, schedule_batch,
                                  (pb, et, nt, tc, tb, key), kw)
         return out

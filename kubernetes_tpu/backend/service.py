@@ -10,8 +10,10 @@ requests carry only dirty rows and the pod batch.
 
 Three pieces:
   * ``DeviceService`` — transport-agnostic server core owning a DeviceState
-    and the compiled batch program; the hot path mirrors TPUScheduler's
-    device half (delta sync, capacity growth, adopt-on-dispatch).
+    and the compiled batch program. Its device half is TPUScheduler's own:
+    both run the shared step in backend/device_step.py (capacity growth,
+    the program's arguments, the dispatch, the preemption screen); the
+    service adds the session/hold bookkeeping around it.
   * ``serve``/``DeviceServiceHTTP`` — stdlib HTTP/JSON binding on localhost
     (the in-process path stays the fast mode; this seam exists to measure
     and bound the serialization/transport cost the reference pays at
@@ -51,16 +53,17 @@ import numpy as np
 from ..api.codec import from_wire, to_wire
 from ..api.types import Node, Pod
 from ..framework.types import Diagnosis, NodeInfo, QueuedPodInfo
-from ..framework.interface import CycleState, Status
+from ..framework.interface import Status
 from ..metrics import latency_ledger
 from ..ops.encode import CapacityError
 from ..scheduler.scheduler import Scheduler
 from ..testing import locktrace
 from ..utils import heap, tracing
 from . import telemetry, wire
-from .batch import build_schedule_batch_fn
+from . import device_step
+from .batch import ATTRIBUTION_ORDER, bucket_sig, build_schedule_batch_fn
 from .circuit import CircuitBreaker, OPEN, STATE_VALUES
-from .device_state import DeviceState, caps_for_cluster
+from .device_state import DeviceState
 from .errors import (
     ConflictError,
     DeviceServiceError,
@@ -70,7 +73,6 @@ from .errors import (
     TransientDeviceError,
     raise_injected_fault,
 )
-from .tpu_scheduler import _ATTRIBUTION_ORDER, TPUScheduler
 
 API_VERSION = "ktpu/v1"
 
@@ -623,62 +625,37 @@ class DeviceService:
             if hold.node_name == name:
                 del self.holds[key]
 
-    def _ensure_device(self, min_nodes: int = 0) -> None:  # ktpu: locked
-        import dataclasses
+    def _ns_labels(self, ns: str) -> Dict[str, str]:  # ktpu: locked
+        # the mirror's ns_labels_fn: device.sync calls it, and the device
+        # syncs only under the service lock
+        return self.ns_labels.get(ns, {})
 
-        n = max(len(self.infos), min_nodes, 1)
-        ns_fn = lambda ns: self.ns_labels.get(ns, {})  # noqa: E731  # ktpu: unguarded-ok(invoked by device.sync, which only runs under the service lock)
-        if self.device is None:
-            self.device = DeviceState(caps_for_cluster(n, batch=self.batch_size),
-                                      ns_labels_fn=ns_fn)
-        elif self.device.caps.nodes < n:
-            caps = self.device.caps
-            nodes = caps.nodes
-            while nodes < n:
-                nodes *= 2
-            self.device = DeviceState(dataclasses.replace(
-                caps, nodes=nodes,
-                value_words=max(caps.value_words, (nodes + 2 + 31) // 32)),
-                ns_labels_fn=ns_fn)
+    def _ensure_device(self, min_nodes: int = 0) -> None:  # ktpu: locked
+        self.device = device_step.fit_nodes(
+            self.device, max(len(self.infos), min_nodes, 1),
+            batch=self.batch_size, ns_labels_fn=self._ns_labels)
+
+    def _grow(self, err: CapacityError) -> None:  # ktpu: locked
+        self.device = device_step.grown(self.device, err,
+                                        ns_labels_fn=self._ns_labels)
 
     def _sync(self, min_nodes: int = 0) -> None:  # ktpu: locked
         self._ensure_device(min_nodes)
-        for _attempt in range(8):
-            try:
-                # deliberate blocking-under-lock: the mirror the device syncs
-                # from must not change until the batch that judged against it
-                # commits — the commit-time validation contract
-                locktrace.note_blocking(
-                    "device_sync", "DeviceService.sync",
-                    allowed="mirror must stay frozen from sync to commit")
-                with tracing.span("device.sync"):
-                    self.device.sync(self.snap)
-                return
-            except CapacityError as e:
-                self._grow(e)
-        # typed per the taxonomy: deterministic (the same delta re-raises),
-        # so the client must never burn retry budget on it
-        raise PermanentDeviceError("device capacities refuse to converge")
 
-    def _grow(self, err: CapacityError) -> None:  # ktpu: locked
-        import dataclasses
+        def sync():  # ktpu: locked
+            # deliberate blocking-under-lock: the mirror the device syncs
+            # from must not change until the batch that judged against it
+            # commits — the commit-time validation contract
+            locktrace.note_blocking(
+                "device_sync", "DeviceService.sync",
+                allowed="mirror must stay frozen from sync to commit")
+            with tracing.span("device.sync"):
+                self.device.sync(self.snap)
 
-        caps = self.device.caps
-        fields = TPUScheduler._GROW_FIELDS.get(err.dimension)
-        if fields is None and err.dimension.startswith("value vocab"):
-            fields = ("value_words",)
-        if fields is None:
-            raise PermanentDeviceError(
-                f"unknown capacity dimension {err.dimension!r}") from err
-        updates = {}
-        for f in fields:
-            v = getattr(caps, f)
-            while v < err.needed:
-                v *= 2
-            updates[f] = v
-        self.device = DeviceState(
-            dataclasses.replace(caps, **updates),
-            ns_labels_fn=lambda ns: self.ns_labels.get(ns, {}))  # ktpu: unguarded-ok(invoked by device.sync, which only runs under the service lock)
+        if not device_step.retry_grown(sync, self._grow)[0]:
+            # typed per the taxonomy: deterministic (the same delta
+            # re-raises), so the client must never burn retry budget on it
+            raise PermanentDeviceError("device capacities refuse to converge")
 
     # --------------------------------------------------------------- health
     def health(self, req: dict) -> dict:
@@ -799,44 +776,26 @@ class DeviceService:
             s.batches += 1
             cid = s.client_id
             self._ensure_device()
-            for _attempt in range(8):
-                try:
-                    with tracing.span("device.sync"):
-                        self.device.sync(self.snap)
-                    with tracing.span("device.encode", batch=len(pods)):
-                        pb, et = self.device.encoder.encode_pods(
-                            pods, tie_seeds=tie_seeds)
-                        tb = self.device.sig_table.encode_topo(pods)
-                    break
-                except CapacityError as e:
-                    self._grow(e)
-            else:
+
+            def sync_encode():  # ktpu: locked
+                with tracing.span("device.sync"):
+                    self.device.sync(self.snap)
+                with tracing.span("device.encode", batch=len(pods)):
+                    # no capacity: every request runs the full pod axis
+                    return device_step.encode(self.device, pods,
+                                              capacity=None,
+                                              tie_seeds=tie_seeds)
+
+            ok, enc = device_step.retry_grown(sync_encode, self._grow)
+            if not ok:
                 raise PermanentDeviceError(
                     "device capacities refuse to converge")
+            pb, et, tb = enc
             host_pb = self.device.encoder.last_host_pb
             self.batch_counter += 1
-            # sampling parity with the in-process batched path: explicit
-            # percentage → exact rotating-window emulation; adaptive (0) →
-            # full batch on accelerators, reference adaptive sample on CPU
-            # (the tpu_scheduler._flush_batch rule)
-            from ..scheduler.scheduler import num_feasible_nodes_to_find
-            from .tpu_scheduler import _default_full_batch
-
-            n_valid = len(self.infos)
-            if self.percentage_of_nodes_to_score:
-                k = num_feasible_nodes_to_find(n_valid,
-                                               self.percentage_of_nodes_to_score)
-            elif _default_full_batch():
-                k = n_valid
-            else:
-                k = num_feasible_nodes_to_find(n_valid, 0)
-            if k < n_valid:
-                sample_k = np.int32(k)
-                sample_start = (self._start_carry if self._start_carry is not None
-                                else np.int32(0))
-            else:
-                sample_k = None
-                sample_start = None
+            sample = device_step.sample_window(
+                self.percentage_of_nodes_to_score, len(self.infos),
+                self._start_carry)
             # resource.k8s.io claims: the client ships pre-resolved selector
             # rows (it has the store; this process does not) and the mask
             # builds against THIS device's attribute table — the same
@@ -848,48 +807,20 @@ class DeviceService:
                 pad_to = len(host_pb["req"])
                 dra_mask = build_dra_mask(
                     self.device, wire_claims_to_entries(claims), pad_to)
-            # slice gangs: the server sees the actual Pod objects, so the
-            # member bucketing mirrors the in-process _slice_batch_args and
-            # the in-jit planner runs identically on both transports
-            slice_members = slice_grid = None
-            slice_groups: Dict[str, List[int]] = {}
-            from ..framework.plugins.coscheduling import pod_group_key
-            from ..ops.slice import is_slice_pod
-
-            for i, pod in enumerate(pods):
-                if is_slice_pod(pod):
-                    gkey = pod_group_key(pod)
-                    if gkey is not None:
-                        slice_groups.setdefault(gkey, []).append(i)
-            if slice_groups:
-                from .claim_mask import _bucket
-
-                g_cap = _bucket(len(slice_groups), floor=2)
-                m_cap = _bucket(
-                    max(len(v) for v in slice_groups.values()), floor=2)
-                member_idx = np.full((g_cap, m_cap), -1, np.int32)
-                member_valid = np.zeros((g_cap, m_cap), bool)
-                for g, gkey in enumerate(slice_groups):
-                    for m, i in enumerate(slice_groups[gkey]):
-                        member_idx[g, m] = i
-                        member_valid[g, m] = True
-                slice_members = (member_idx, member_valid)
-                slice_grid = (self.device.caps.superpods,
-                              self.device.caps.sp_slots)
-            bucket = int(getattr(pb, "capacity", len(pods)))
+            bucket = int(pb.capacity)
             # namespace-quota screen: sync the client-shipped ledger table
             # into this device and build the batch's ns/req columns — the
             # same builder the in-process dispatch uses, so both transports
             # screen identically
-            quota_ns = quota_req = None
+            quota = (None, None)
             if self.quota_table or self.device.nsq_slots:
                 from ..ops.quota import build_quota_batch_args
 
-                quota_ns, quota_req = build_quota_batch_args(
+                quota = build_quota_batch_args(
                     pods, self.device, table=self.quota_table,
                     pad_to=bucket)
-            sig = f"{bucket}/" + (
-                "general" if self.device.topo_enabled else "off")
+            slices = device_step.slice_args(pods, self.device)
+            sig = bucket_sig(bucket, None, self.device.topo_enabled)
             telemetry.event("dispatch", batchId=batch_id, client=cid,
                             epoch=self.epoch, bucket=bucket, sig=sig,
                             pods=len(pods))
@@ -901,19 +832,14 @@ class DeviceService:
                 "device_dispatch", "DeviceService.schedule_batch",
                 allowed="kernel must judge under the same lock as commit")
             with tracing.span("device.dispatch", batch=len(pods)):
-                with telemetry.dispatch("schedule_batch", bucket=sig):
-                    result = self.schedule_batch_fn(
-                        pb, et, self.device.nt, self.device.tc, tb,
-                        np.int32(self.batch_counter),
-                        topo_enabled=self.device.topo_enabled,
-                        sample_k=sample_k, sample_start=sample_start,
-                        dra_mask=dra_mask, slice_members=slice_members,
-                        slice_grid=slice_grid,
-                        quota_ns=quota_ns, quota_req=quota_req,
-                        quota_used=(self.device.nsq_used
-                                    if quota_ns is not None else None),
-                        quota_limit=(self.device.nsq_limit
-                                     if quota_ns is not None else None))
+                # the served program: no topology mode, the ports filter
+                # always compiled in, no carry or volume mask
+                result = device_step.dispatch(
+                    self.schedule_batch_fn, self.device, pb, et, tb,
+                    np.int32(self.batch_counter), topo_mode=None,
+                    vd_override=None, host_key=0, ports_enabled=True,
+                    topo_carry=None, extra_mask=None, dra_mask=dra_mask,
+                    sample=sample, slices=slices, quota=quota)
             t_dispatch = self.now_fn()
             if result.final_sample_start is not None:
                 self._start_carry = result.final_sample_start
@@ -935,7 +861,7 @@ class DeviceService:
                     program="schedule_batch", bucket=sig,
                     t_submit=t_dispatch, now_fn=self.now_fn,
                     batch_id=batch_id, pods=len(pods),
-                    quota_col=quota_ns is not None,
+                    quota_col=quota[0] is not None,
                     event_extra={"client": cid})
                 self.device.adopt_device(result)
                 self.device.adopt_commits(result, host_pb, node_idx)
@@ -970,14 +896,9 @@ class DeviceService:
             screen = best = None
             if any(int(node_idx[i]) < 0 for i in range(len(pods))):
                 try:
-                    from ..ops.preempt import screen_prefix
-
-                    self.device._refresh_class_prio()
-                    with telemetry.dispatch("preempt_screen",
-                                            bucket=str(bucket)):
-                        pres = screen_prefix(pb, self.device.nt,
-                                             result.static_masks,
-                                             node_idx[:len(pods)] < 0)
+                    pres = device_step.screen_preemption(
+                        self.device, pb, result.static_masks,
+                        node_idx[:len(pods)] < 0)
                     screen = np.asarray(pres.screen)
                     best = np.asarray(pres.best)
                 except Exception:  # noqa: BLE001 — hints are optional
@@ -1003,11 +924,11 @@ class DeviceService:
                     if fid > 0:
                         plugins.add(fid)
                         if len(statuses) < 64:  # payload-bounded sample
-                            statuses[name] = _ATTRIBUTION_ORDER[fid - 1][0]
+                            statuses[name] = ATTRIBUTION_ORDER[fid - 1][0]
                 r = {
                     "nodeName": None,
                     "unschedulablePlugins": [
-                        _ATTRIBUTION_ORDER[fid - 1][0] for fid in sorted(plugins)],
+                        ATTRIBUTION_ORDER[fid - 1][0] for fid in sorted(plugins)],
                     "statuses": statuses,
                 }
                 if screen is not None:
@@ -1026,12 +947,12 @@ class DeviceService:
                         # still helps (preferred-node fast path)
                         r["preempt"] = {"candidates": None, "best": best_name}
                 results.append(r)
-            if slice_words is not None and slice_groups:
+            if slice_words is not None and slices[0] is not None:
                 # ship each member's verdict word so the client can split
                 # plan-infeasible from lost-in-flight without a second trip
-                for idxs in slice_groups.values():
-                    for i in idxs:
-                        results[i]["slice"] = int(slice_words[i])
+                member_idx, member_valid = slices[0]
+                for i in member_idx[member_valid]:
+                    results[i]["slice"] = int(slice_words[i])
             if quota_words is not None:
                 # every screened pod's quota verdict word rides back: the
                 # client rejects flagged winners against its authoritative
@@ -2521,7 +2442,7 @@ class WireScheduler(Scheduler):
             else:
                 d = Diagnosis()
                 for name, plugin in (r.get("statuses") or {}).items():
-                    reason = dict(_ATTRIBUTION_ORDER).get(plugin, "unschedulable")
+                    reason = dict(ATTRIBUTION_ORDER).get(plugin, "unschedulable")
                     d.node_to_status[name] = Status.unschedulable(reason).with_plugin(plugin)
                 d.unschedulable_plugins.update(r.get("unschedulablePlugins") or ())
                 state = self._new_cycle_state()

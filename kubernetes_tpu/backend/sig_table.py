@@ -69,9 +69,6 @@ class _Term:
     klass: int
     term: AffinityTerm
 
-    def carried_key(self) -> TermKey:
-        return term_key_of(self.term, self.klass)
-
 
 def term_key_of(term: AffinityTerm, klass: int) -> TermKey:
     return (
@@ -242,9 +239,6 @@ class SigTable:
             self.version += 1
 
     # ---------------------------------------------------------------- matching
-
-    def sig_matches_pod(self, sid: int, pod: Pod) -> bool:
-        return self._sig_rows[sid].matches(pod, self.ns_labels_fn)
 
     def pod_sig_mask(self, pod: Pod) -> np.ndarray:
         """[S] bool: which registered pod-sets this pod belongs to (the in-scan

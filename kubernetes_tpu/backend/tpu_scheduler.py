@@ -24,7 +24,7 @@ import contextlib
 import dataclasses
 import os
 from collections import deque
-from typing import Callable, Deque, Dict, List, Optional, Set, Tuple
+from typing import Callable, Deque, Dict, List, Optional, Set
 
 import jax
 import numpy as np
@@ -39,30 +39,11 @@ from ..ops.encode import CapacityError
 from ..ops.topology import seg_mode
 from ..scheduler.scheduler import Scheduler
 from ..utils import heap
-from .batch import BatchResult, build_schedule_batch_fn, domain_axis
-from .device_state import DeviceState, caps_for_cluster
-from .errors import PermanentDeviceError
-
-# filter config order for failure attribution (default_plugins.go filter order)
-_ATTRIBUTION_ORDER = (
-    ("NodeUnschedulable", "node(s) were unschedulable"),
-    ("NodeName", "node(s) didn't match the requested node name"),
-    ("TaintToleration", "node(s) had untolerated taint"),
-    ("NodeAffinity", "node(s) didn't match Pod's node affinity/selector"),
-    ("NodePorts", "node(s) didn't have free ports for the requested pod ports"),
-    ("NodeResourcesFit", "Insufficient resources"),
-    ("PodTopologySpread", "node(s) didn't match pod topology spread constraints"),
-    ("InterPodAffinity", "node(s) didn't match pod affinity/anti-affinity rules"),
-    ("VolumeBinding", "node(s) didn't satisfy volume placement"),
-    ("DynamicResources", "cannot allocate all claims"),
-    ("SlicePacking", "node(s) outside the gang's planned torus slice"),
-)
-
-
-# _DecayedFit/BatchSizer moved to backend/sizer.py when the wire path
-# gained the same in-flight ring shape (WireScheduler's pipelined
-# transport); re-exported here for the existing call sites and tests.
-from .sizer import BatchSizer, _DecayedFit  # noqa: F401  (re-export)
+from . import device_step
+from .batch import (ATTRIBUTION_ORDER, BatchResult, bucket_sig,
+                    build_schedule_batch_fn, domain_axis)
+from .device_state import DeviceState
+from .sizer import BatchSizer
 
 
 @dataclasses.dataclass
@@ -97,16 +78,6 @@ class _Inflight:
     # packed block then carries a quota verdict column, and the unpack
     # must know which a SINGLE trailing column is (slice vs quota)
     quota_col: bool = False
-
-
-def _default_full_batch() -> bool:
-    """Whether the adaptive percentageOfNodesToScore default (0) evaluates
-    the FULL node batch (accelerators) or the reference's adaptive sample
-    (CPU). KTPU_FULL_BATCH=1/0 overrides the platform choice."""
-    env = os.environ.get("KTPU_FULL_BATCH", "")
-    if env in ("0", "1"):
-        return env == "1"
-    return jax.default_backend() != "cpu"
 
 
 # the fixed in-checkout cache directory: the path is part of the cache key,
@@ -233,8 +204,8 @@ class TPUScheduler(Scheduler):
         # own state, while KTPU_LOCKTRACE traces the protocol end to end)
         # serializes the two owners' device-touching phases: the scheduling
         # thread's sync/encode/dispatch vs the worker's adopt/judge/
-        # reconcile. PLATFORM-AWARE default (the _default_full_batch rule):
-        # on an accelerator the device executes off-host and the worker's
+        # reconcile. PLATFORM-AWARE default (device_step.default_full_batch's
+        # rule): on an accelerator the device executes off-host and the worker's
         # overlap is free; on the CPU fallback "device compute" is host CPU
         # time, so a second thread only contends with XLA (measured ~18%
         # slower on the 2-core bench box) — commits stay inline there.
@@ -314,69 +285,25 @@ class TPUScheduler(Scheduler):
             self._slot_reuses_seen = reuses
 
     def _ensure_device(self) -> None:
-        """Build or grow the device mirror. Always called on the scheduling
-        thread; drains (commit-worker flush included) happen OUTSIDE the
-        device mutex — the worker needs the mutex to finish its commits —
-        and the rebuild+sync run under it."""
+        """Build or grow the device mirror (device_step.fit_nodes). Always
+        called on the scheduling thread; a grow first drains OUTSIDE the
+        device mutex (the commit worker needs the mutex to finish its
+        commits: old-device results must land first), then the rebuild and
+        sync run under it."""
         n = max(self.cache.node_count(), 1)
         with self.commit_plane.device_mutex:
             device = self.device
-            needs_grow = device is not None and device.caps.nodes < n
-        if device is None:
-            with self.commit_plane.device_mutex:
-                if self.device is None:
-                    self.device = DeviceState(
-                        caps_for_cluster(n, batch=self.batch_size),
-                        ns_labels_fn=self.store.ns_labels)
-                    self.device.sync(self.snapshot)
-            return
-        if not needs_grow:
-            return
-        # preserve every previously-grown axis; only widen the node axis
-        # (and the hostname value vocab that must cover it)
-        self._drain_inflight()  # old-device results must commit first
-        if self.device is None:  # the drain's commit killed the device
-            self._ensure_device()
-            return
+        if device is not None:
+            if device.caps.nodes >= n:
+                return
+            self._drain_inflight()
         with self.commit_plane.device_mutex:
-            caps = self.device.caps
-            nodes = caps.nodes
-            while nodes < n:
-                nodes *= 2
-            caps = dataclasses.replace(
-                caps, nodes=nodes,
-                value_words=max(caps.value_words, (nodes + 2 + 31) // 32),
-            )
-            self.device = DeviceState(caps, ns_labels_fn=self.store.ns_labels)
-            self.device.sync(self.snapshot)
-
-    # CapacityError.dimension → Capacities field(s) to double (exact names
-    # raised by ops/encode.py; "value vocab for 'key'" handled by prefix)
-    _GROW_FIELDS = {
-        "nodes": ("nodes",),
-        "pods": ("pods",),
-        "resources": ("resources",),
-        "label_keys": ("label_keys",),
-        "taints": ("taints",),
-        "tolerations": ("tolerations",),
-        "exprs": ("exprs",),
-        "sel_exprs": ("sel_exprs",),
-        "terms": ("terms",),
-        "term_exprs": ("term_exprs",),
-        "pref_terms": ("pref_terms",),
-        "ports": ("ports",),
-        "ports vocab": ("port_words",),
-        "image vocab": ("image_words", "images"),
-        "containers": ("containers",),
-        "sigs": ("sigs",),
-        "ex_terms": ("ex_terms",),
-        "spread_cons": ("spread_cons",),
-        "ipa_terms": ("ipa_terms",),
-        "ipa_pref": ("ipa_pref",),
-        "prio_classes": ("prio_classes",),
-        "superpods": ("superpods",),
-        "sp_slots": ("sp_slots",),
-    }
+            device = device_step.fit_nodes(self.device, n,
+                                           batch=self.batch_size,
+                                           ns_labels_fn=self.store.ns_labels)
+            if device is not self.device:
+                self.device = device
+                device.sync(self.snapshot)
 
     def _resync_grown(self, err: CapacityError) -> None:
         """Grow exactly the offending capacity axis and rebuild the mirror.
@@ -386,23 +313,9 @@ class TPUScheduler(Scheduler):
         if self.device is None:  # the drain's commit killed the device
             self._ensure_device()
             return
-        fields = self._GROW_FIELDS.get(err.dimension)
-        if fields is None and err.dimension.startswith("value vocab"):
-            fields = ("value_words",)
-        if fields is None:
-            # typed per backend/errors.py: deterministic, never retried
-            raise PermanentDeviceError(
-                f"unknown capacity dimension {err.dimension!r}") from err
         with self.commit_plane.device_mutex:
-            caps = self.device.caps
-            updates = {}
-            for f in fields:
-                v = getattr(caps, f)
-                while v < err.needed:
-                    v *= 2
-                updates[f] = v
-            self.device = DeviceState(dataclasses.replace(caps, **updates),
-                                      ns_labels_fn=self.store.ns_labels)
+            self.device = device_step.grown(self.device, err,
+                                            ns_labels_fn=self.store.ns_labels)
             self.device.sync(self.snapshot)
 
     # ------------------------------------------------------------- batch support
@@ -649,8 +562,6 @@ class TPUScheduler(Scheduler):
             with mutex:
                 enc = self._try_pipelined_encode(batched)
                 device = self.device  # instance the encode ran against
-        extra_mask = None
-        dra_mask = None
         if enc is not None:
             pb, et, tb, extra_mask, dra_mask = enc
             t_sync = t0  # nothing to upload: the in-flight carry IS the state
@@ -664,38 +575,27 @@ class TPUScheduler(Scheduler):
             # the next pipelined probe (conservative break, never a miss)
             ext_seq = self.external_change_seq()
             self.cache.update_snapshot(self.snapshot)
-            for _attempt in range(8):
-                try:
-                    with mutex:
-                        with tracing.span("device.sync"):
-                            self.device.sync(self.snapshot)
-                        self._sync_slot_reuse_metric()
-                        t_sync = self.now_fn()
-                        pods = [qp.pod for qp in batched]
-                        bucket = self.sizer.bucket_for(len(pods))
-                        from ..ops.tiebreak import seeds_for
 
-                        with tracing.span("device.encode", batch=len(batched)):
-                            pb, et = self.device.encoder.encode_pods(
-                                pods, capacity=bucket,
-                                tie_seeds=seeds_for(batched))
-                            tb = self.device.sig_table.encode_topo(
-                                pods, capacity=bucket)
-                            extra_mask = self._volume_masks.build(
-                                batched, self.snapshot, self.device.encoder,
-                                self.device.caps.nodes, bucket)
-                            dra_mask = self._claim_masks.build(
-                                batched, self.device, bucket)
-                        device = self.device
-                    break
-                except CapacityError as e:
-                    # outside the mutex: the grow path drains, and the
-                    # commit worker needs the mutex to finish its commits
-                    self._resync_grown(e)
-            else:
+            def sync_encode():
+                with mutex:
+                    with tracing.span("device.sync"):
+                        self.device.sync(self.snapshot)
+                    self._sync_slot_reuse_metric()
+                    t_sync = self.now_fn()
+                    with tracing.span("device.encode", batch=len(batched)):
+                        pb, et, tb, extra_mask, dra_mask = self._encode(
+                            batched)
+                    return (pb, et, tb, extra_mask, dra_mask, t_sync,
+                            self.device)
+
+            # a grow runs outside the mutex: it drains, and the commit
+            # worker needs the mutex to finish its commits
+            ok, out = device_step.retry_grown(sync_encode, self._resync_grown)
+            if not ok:
                 for qp in batched:  # capacities refuse to converge
                     self._schedule_fallback(qp, pod_cycle)
                 return
+            pb, et, tb, extra_mask, dra_mask, t_sync, device = out
             self._chain_ext_seq = ext_seq
             self._chain_dirty = False
         t_enc = self.now_fn()
@@ -717,34 +617,9 @@ class TPUScheduler(Scheduler):
         carry = None
         if prev is not None and prev.result.final_sel_counts is not None:
             carry = (prev.result.final_sel_counts, prev.result.final_seg_exist)
-        # percentageOfNodesToScore: an EXPLICIT percentage gets the exact
-        # rotating-window emulation (schedule_one.go:525-545 parity). The
-        # adaptive default (0) is PLATFORM-AWARE:
-        #   * accelerators run FULL-batch evaluation — the reference's
-        #     adaptive mode exists to bound per-cycle CPU time by examining
-        #     fewer nodes, but on TPU the masked full evaluation is cheaper
-        #     than the emulated early-exit (SURVEY §2.7 P2) and it unlocks
-        #     the speculative-decode program. Documented divergence per
-        #     SURVEY §7 hard-part 3.
-        #   * the CPU fallback keeps the reference's adaptive sampling
-        #     (50 − N/125 floored at 5%): on CPU the scan step cost is real
-        #     host time exactly as in the reference, so the reference's own
-        #     bound applies — and the default config then reproduces
-        #     reference placement semantics on CPU (VERDICT r3 weak #7).
-        n_valid = self.cache.node_count()
-        if self.percentage_of_nodes_to_score:
-            k = self.num_feasible_nodes_to_find(n_valid)
-        elif _default_full_batch():
-            k = n_valid
-        else:
-            k = self.num_feasible_nodes_to_find(n_valid)
-        if k < n_valid:
-            sample_k = np.int32(k)
-            sample_start = (self._start_carry if self._start_carry is not None
-                            else np.int32(0))
-        else:
-            sample_k = None
-            sample_start = None
+        sample = device_step.sample_window(self.percentage_of_nodes_to_score,
+                                           self.cache.node_count(),
+                                           self._start_carry)
         with mutex:
             if self.device is not device:
                 # a worker-side poison killed (or a rebuild replaced) the
@@ -763,16 +638,11 @@ class TPUScheduler(Scheduler):
             topo_mode, vd_bucket, host_key = mode_info
             telemetry.event("encode", batchId=batch_id, bucket=bucket,
                             pods=len(batched), pipelined=enc is not None)
-            # slice gangs plan in-jit (ops/slice.py): hand the batch program
-            # the bucketed member index so verdicts ride the packed block
-            slice_members, slice_grid = self._slice_batch_args(batched,
-                                                               device)
-            # namespace-quota screen (ops/quota.py): sync the ledger's
-            # used/limit rows into the device and hand the program the
-            # batch's ns/req columns — the over-quota verdict column rides
-            # the packed block, zero extra dispatch
-            quota_ns, quota_req = self._quota_batch_args(batched, device,
-                                                         bucket)
+            quota = self._quota_batch_args(batched, device, bucket)
+            # slice gangs plan in-jit (ops/slice.py): the member index rides
+            # in, the verdicts ride the packed block out
+            slices = device_step.slice_args([qp.pod for qp in batched],
+                                            device)
             span_attrs = {"topo": topo_mode}
             if topo_mode == "general":
                 # the domain axis and the segment-sum form the program
@@ -780,28 +650,18 @@ class TPUScheduler(Scheduler):
                 vd = domain_axis(et.bits.shape[1], vd_bucket)
                 span_attrs.update(vd=vd, seg=seg_mode(vd))
             with tracing.span("device.dispatch", **span_attrs):
-                result = self._run_batch_fn(
-                    pb, et, device.nt, device.tc, tb, key,
-                    adopt=True,
-                    topo_enabled=device.topo_enabled,
-                    topo_carry=carry,
-                    sample_k=sample_k,
-                    sample_start=sample_start,
-                    topo_mode=topo_mode,
-                    vd_override=vd_bucket,
+                result = device_step.dispatch(
+                    self.schedule_batch_fn, device, pb, et, tb, key,
+                    topo_mode=topo_mode, vd_override=vd_bucket,
                     host_key=host_key,
                     ports_enabled=device.encoder.last_has_ports,
-                    extra_mask=extra_mask,
-                    dra_mask=dra_mask,
-                    slice_members=slice_members,
-                    slice_grid=slice_grid,
-                    quota_ns=quota_ns,
-                    quota_req=quota_req,
-                    quota_used=device.nsq_used if quota_ns is not None
-                    else None,
-                    quota_limit=device.nsq_limit if quota_ns is not None
-                    else None,
-                )
+                    topo_carry=carry, extra_mask=extra_mask,
+                    dra_mask=dra_mask, sample=sample, slices=slices,
+                    quota=quota)
+                # the program's evolved arrays (still futures) become the
+                # device truth now; the host mirror advances at commit
+                # (adopt_commits), where node_idx is materialized anyway
+                device.adopt_device(result)
             if result.final_sample_start is not None:
                 # keep the rotation index across unsampled batches too (the
                 # reference's nextStartNodeIndex persists across attempts) —
@@ -823,10 +683,8 @@ class TPUScheduler(Scheduler):
                                             batch_id, bucket,
                                             device.encoder.reclaim_gen,
                                             device, t_dispatch,
-                                            quota_col=quota_ns is not None))
-        # sig mirrors _run_batch_fn's compile-ledger bucket signature so the
-        # flight recorder, compile ledger, and dispatch ledger key alike
-        sig = f"{bucket}/{topo_mode or ('general' if device.topo_enabled else 'off')}"
+                                            quota_col=quota[0] is not None))
+        sig = bucket_sig(bucket, topo_mode, device.topo_enabled)
         telemetry.event("dispatch", batchId=batch_id, bucket=bucket,
                         pods=len(batched), topo=topo_mode, sig=sig,
                         packed=result.packed is not None,
@@ -873,8 +731,9 @@ class TPUScheduler(Scheduler):
         batch's adopted device carry — legal only when (a) nothing external
         touched the cluster since the in-flight dispatch and (b) encoding
         registers no new signature/term (a fresh row is backfilled from host
-        counts that cannot see the in-flight commits). Returns (pb, et, tb)
-        or None to take the drain+sync path. Caller holds the device mutex."""
+        counts that cannot see the in-flight commits). Returns _encode's
+        tuple, or None to take the drain+sync path. Caller holds the device
+        mutex."""
         if not self.pipeline_depth or not self._inflight or self.device is None:
             return None
         if self.commit_worker is not None:
@@ -899,17 +758,7 @@ class TPUScheduler(Scheduler):
         st = self.device.sig_table
         vocab0 = (st.n_sigs, st.n_terms)
         try:
-            from ..ops.tiebreak import seeds_for
-
-            pods = [qp.pod for qp in batched]
-            bucket = self.sizer.bucket_for(len(pods))
-            pb, et = self.device.encoder.encode_pods(
-                pods, capacity=bucket, tie_seeds=seeds_for(batched))
-            tb = st.encode_topo(pods, capacity=bucket)
-            extra_mask = self._volume_masks.build(
-                batched, self.snapshot, self.device.encoder,
-                self.device.caps.nodes, bucket)
-            dra_mask = self._claim_masks.build(batched, self.device, bucket)
+            enc = self._encode(batched)
         except CapacityError:
             return None  # grow via the drain+sync path (idempotent re-encode)
         if (st.n_sigs, st.n_terms) != vocab0:
@@ -918,6 +767,21 @@ class TPUScheduler(Scheduler):
             # the carry shapes (seg_exist vs term_cnt, vd bucket) differ —
             # land the in-flight batches and restart the chain on host truth
             return None
+        return enc
+
+    def _encode(self, batched: List[QueuedPodInfo]):
+        """(pb, et, tb, extra_mask, dra_mask) at the batch's sizer bucket,
+        with the volume and claim masks. Caller holds the device mutex."""
+        from ..ops.tiebreak import seeds_for
+
+        bucket = self.sizer.bucket_for(len(batched))
+        pb, et, tb = device_step.encode(
+            self.device, [qp.pod for qp in batched], capacity=bucket,
+            tie_seeds=seeds_for(batched))
+        extra_mask = self._volume_masks.build(
+            batched, self.snapshot, self.device.encoder,
+            self.device.caps.nodes, bucket)
+        dra_mask = self._claim_masks.build(batched, self.device, bucket)
         return pb, et, tb, extra_mask, dra_mask
 
     def _drain_inflight(self) -> None:
@@ -1002,13 +866,13 @@ class TPUScheduler(Scheduler):
                               packed="packed" if packed_ok else "fallback",
                               worker="commit" if on_worker else "inline"):
                 t_wait0 = self.now_fn()
-                mode = (fl.mode_info[0] if fl.mode_info else None) or (
-                    "general" if getattr(fl.device, "topo_enabled", True)
-                    else "off")
+                sig = bucket_sig(
+                    fl.bucket, fl.mode_info[0] if fl.mode_info else None,
+                    getattr(fl.device, "topo_enabled", True))
                 (node_idx, ff, slice_words, quota_words,
                  _), disp = materialize_profiled(
                     fl.result, self.device.caps.nodes,
-                    program="schedule_batch", bucket=f"{fl.bucket}/{mode}",
+                    program="schedule_batch", bucket=sig,
                     t_submit=fl.t_submit or None, now_fn=self.now_fn,
                     batch_id=fl.batch_id, pods=len(fl.qps),
                     quota_col=fl.quota_col,
@@ -1023,12 +887,13 @@ class TPUScheduler(Scheduler):
             with tracing.span("host.commit", batch=len(fl.qps),
                               worker="commit" if on_worker else "inline"):
                 t_host0 = self.now_fn()
-                self._commit_batch(fl.qps, fl.result, fl.pod_cycle, fl.t0,
-                                   node_idx, pb=fl.pb, ff=ff,
-                                   reclaim_gen=fl.reclaim_gen,
-                                   batch_id=fl.batch_id,
-                                   slice_words=slice_words,
-                                   quota_words=quota_words)
+                # the whole commit — winner binds AND loser requeues — runs
+                # inside one queue-move coalescing window: every POD_ADD/
+                # POD_DELETE wave its store events fire is one union scan
+                with self.queue.coalesce_moves():
+                    self._commit_batch(fl.qps, fl.result, fl.pod_cycle, fl.t0,
+                                       node_idx, fl.pb, ff, fl.reclaim_gen,
+                                       fl.batch_id, slice_words, quota_words)
                 self.smetrics.device_batch_duration.observe(
                     self.now_fn() - t_host0, "commit_host")
             # reconcile: the commits above advanced node generations; the
@@ -1169,57 +1034,13 @@ class TPUScheduler(Scheduler):
                     return True
         return False
 
-    def _run_batch_fn(self, *args, adopt=False, **kwargs) -> BatchResult:
-        """Dispatch the compiled batch program (async — nothing here blocks).
-        A program the device's compiler refuses raises: no path swaps in a
-        different program behind the caller's back. With ``adopt``, the
-        program's evolved
-        device arrays (still futures) become the device truth immediately;
-        the HOST mirror advances later, at commit time, when node_idx is
-        materialized anyway (adopt_commits in _commit_inflight — reading
-        node_idx here would force a device sync per dispatch and serialize
-        the pipeline)."""
-        from . import telemetry
-
-        # compile-ledger attribution: bucket signature = padded pod capacity
-        # + topology mode — the two shape axes the sizer/topo walk retraces
-        # over (ops/schema.PodBatch.capacity; kwargs as built by the callers)
-        mode = kwargs.get("topo_mode") or (
-            "general" if kwargs.get("topo_enabled", True) else "off")
-        sig = f"{getattr(args[0], 'capacity', '?')}/{mode}"
-        with telemetry.dispatch("schedule_batch", bucket=sig):
-            result = self.schedule_batch_fn(*args, **kwargs)
-        if adopt:
-            self.device.adopt_device(result)
-        return result
-
     def _commit_batch(self, qps: List[QueuedPodInfo], result: BatchResult,
-                      pod_cycle: int, t0: float,
-                      node_idx: Optional[np.ndarray] = None,
-                      pb=None, ff: Optional[np.ndarray] = None,
-                      reclaim_gen: Optional[int] = None,
-                      batch_id: str = "",
-                      slice_words: Optional[np.ndarray] = None,
-                      quota_words: Optional[np.ndarray] = None) -> None:
-        if node_idx is None:
-            node_idx = np.asarray(result.node_idx)
-        # the whole commit — winner binds AND loser requeues — runs inside
-        # one queue-move coalescing window: every POD_ADD/POD_DELETE wave
-        # the commit's store events fire collapses into one union scan
-        with self.queue.coalesce_moves():
-            self._commit_batch_coalesced(qps, result, pod_cycle, t0,
-                                         node_idx, pb, ff, reclaim_gen,
-                                         batch_id, slice_words, quota_words)
-
-    def _commit_batch_coalesced(self, qps: List[QueuedPodInfo],
-                                result: BatchResult, pod_cycle: int,
-                                t0: float, node_idx: np.ndarray,
-                                pb=None, ff: Optional[np.ndarray] = None,
-                                reclaim_gen: Optional[int] = None,
-                                batch_id: str = "",
-                                slice_words: Optional[np.ndarray] = None,
-                                quota_words: Optional[np.ndarray] = None
-                                ) -> None:
+                      pod_cycle: int, t0: float, node_idx: np.ndarray, pb,
+                      ff: Optional[np.ndarray], reclaim_gen: Optional[int],
+                      batch_id: str, slice_words: Optional[np.ndarray],
+                      quota_words: Optional[np.ndarray]) -> None:
+        """The host commit of one landed batch; the caller holds the
+        queue's move-coalescing window."""
         # ledger: claim time — the batch leaves the device ring and enters
         # the host commit tail (one lock round trip for the whole batch)
         latency_ledger.transition_many(
@@ -1338,32 +1159,12 @@ class TPUScheduler(Scheduler):
                 preempt_hints = (screen, best, dict(self.device.encoder.node_slots))
             if preempt_hints is None:
                 try:
-                    from ..ops.preempt import screen_prefix
-                    from . import telemetry
-
+                    # the screen's class refresh replaces device.nt: it must
+                    # not interleave with a dispatch's adopt
                     with self.commit_plane.device_mutex:
-                        # a priority class first seen this cycle is still
-                        # INT_MAX on device (= never evictable) unless
-                        # refreshed now; the refresh replaces device.nt, so
-                        # it must not interleave with a dispatch's adopt
-                        self.device._refresh_class_prio()
-                        with telemetry.dispatch(
-                                "preempt_screen",
-                                bucket=str(getattr(pb, "capacity", "?"))):
-                            pres = screen_prefix(pb, self.device.nt,
-                                                 result.static_masks,
-                                                 node_idx[:len(qps)] < 0)
-                        if telemetry.get() is not None:
-                            from ..ops.preempt import _screen_jit
-
-                            failed_pad = np.zeros(pb.capacity, bool)
-                            failed_pad[:len(qps)] = node_idx[:len(qps)] < 0
-                            telemetry.cost_probe(
-                                "preempt_screen",
-                                str(getattr(pb, "capacity", "?")),
-                                _screen_jit,
-                                (pb, self.device.nt, result.static_masks,
-                                 failed_pad))
+                        pres = device_step.screen_preemption(
+                            self.device, pb, result.static_masks,
+                            node_idx[:len(qps)] < 0)
                     from ..utils import device_sync
 
                     device_sync.count_sync("preempt-read")
@@ -1612,35 +1413,6 @@ class TPUScheduler(Scheduler):
                 plugin.reject_gang(gkey, reason)
         return rejected
 
-    def _slice_batch_args(self, batched: List[QueuedPodInfo], device):
-        """Bucketed member index of the batch's slice gangs (ops/slice.py
-        marker label + PodGroup key), or (None, None) when the batch has
-        none — the common case, whose batch program is unchanged. Member
-        rows follow batch order (= queue order), the same ordinal the host
-        oracle assigns."""
-        from ..ops.slice import is_slice_pod
-
-        groups: Dict[str, List[int]] = {}
-        for i, qp in enumerate(batched):
-            if is_slice_pod(qp.pod):
-                gkey = pod_group_key(qp.pod)
-                if gkey is not None:
-                    groups.setdefault(gkey, []).append(i)
-        if not groups:
-            return None, None
-        from .claim_mask import _bucket
-
-        g_cap = _bucket(len(groups), floor=2)
-        m_cap = _bucket(max(len(v) for v in groups.values()), floor=2)
-        member_idx = np.full((g_cap, m_cap), -1, np.int32)
-        member_valid = np.zeros((g_cap, m_cap), bool)
-        for g, gkey in enumerate(groups):
-            for m, i in enumerate(groups[gkey]):
-                member_idx[g, m] = i
-                member_valid[g, m] = True
-        return ((member_idx, member_valid),
-                (device.caps.superpods, device.caps.sp_slots))
-
     def _quota_batch_args(self, batched: List[QueuedPodInfo], device,
                           bucket: int):
         """(ns_idx, req) columns for the batch program's namespace-quota
@@ -1746,7 +1518,7 @@ class TPUScheduler(Scheduler):
     # ~15ms of the Unschedulable row's tail
     _SHARED_STATUSES = tuple(
         Status.unschedulable(reason).with_plugin(plugin)
-        for plugin, reason in _ATTRIBUTION_ORDER)
+        for plugin, reason in ATTRIBUTION_ORDER)
 
     def _diagnose(self, ff_row: np.ndarray, slot_names: Dict[int, str]) -> Diagnosis:
         """Per-node first-failing plugin in filter config order, read straight
@@ -1846,33 +1618,22 @@ class TPUScheduler(Scheduler):
         # registration pass: encoding the sample grows the sig/term tables
         # FIRST, so the topo-mode decision below matches what the real
         # batches will select (capacity growth retried like _flush_batch)
-        for _attempt in range(8):
-            try:
-                self.device.encoder.encode_pods(
-                    pods_for_warm,
-                    capacity=self.sizer.bucket_for(len(pods_for_warm)))
-                self.device.sig_table.encode_topo(
-                    pods_for_warm,
-                    capacity=self.sizer.bucket_for(len(pods_for_warm)))
-                break
-            except CapacityError as e:
-                self._resync_grown(e)
-        else:
+        ok, _ = device_step.retry_grown(
+            lambda: device_step.encode(
+                self.device, pods_for_warm,
+                capacity=self.sizer.bucket_for(len(pods_for_warm)),
+                tie_seeds=None),
+            self._resync_grown)
+        if not ok:
             import logging
 
             logging.getLogger(__name__).warning(
                 "warm_buckets: capacities refused to converge for the "
                 "sample; warming with unregistered topology (degraded)")
         self.device.sync(self.snapshot)  # refresh counts for new sigs
-        n_valid = self.cache.node_count()
-        if self.percentage_of_nodes_to_score or not _default_full_batch():
-            k = self.num_feasible_nodes_to_find(n_valid)
-        else:
-            k = n_valid
-        sample_k = np.int32(k) if k < n_valid else None
-        sample_start = np.int32(0) if k < n_valid else None
-        mode_info = self._topo_mode_info()
-        topo_mode, vd_bucket, host_key = mode_info
+        sample = device_step.sample_window(self.percentage_of_nodes_to_score,
+                                           self.cache.node_count(), None)
+        topo_mode, vd_bucket, host_key = self._topo_mode_info()
         warmed = 0
         timings = []  # (bucket, warm execution seconds)
         for bucket in sorted({self.sizer.bucket_for(b)
@@ -1881,79 +1642,51 @@ class TPUScheduler(Scheduler):
             # small buckets are exactly the ones deadline cuts switch to
             warm_slice = pods_for_warm[:bucket]
             try:
-                pb, et = self.device.encoder.encode_pods(warm_slice,
-                                                         capacity=bucket)
-                tb = self.device.sig_table.encode_topo(warm_slice,
-                                                       capacity=bucket)
+                pb, et, tb = device_step.encode(self.device, warm_slice,
+                                                capacity=bucket,
+                                                tie_seeds=None)
             except CapacityError:
                 continue
-            common = dict(adopt=False, topo_enabled=self.device.topo_enabled,
-                          sample_k=sample_k, sample_start=sample_start,
-                          topo_mode=topo_mode, vd_override=vd_bucket,
+            common = dict(topo_mode=topo_mode, vd_override=vd_bucket,
                           host_key=host_key,
-                          ports_enabled=self.device.encoder.last_has_ports)
-            res = self._run_batch_fn(pb, et, self.device.nt, self.device.tc,
-                                     tb, np.int32(0), topo_carry=None, **common)
-            np.asarray(res.node_idx)  # land compile + first execution
+                          ports_enabled=self.device.encoder.last_has_ports,
+                          extra_mask=None, dra_mask=None, sample=sample,
+                          slices=(None, None), quota=(None, None))
+
+            def run(key=0, carry=None, **over):
+                res = device_step.dispatch(
+                    self.schedule_batch_fn, self.device, pb, et, tb,
+                    np.int32(key), topo_carry=carry, **dict(common, **over))
+                np.asarray(res.node_idx)  # land compile + first execution
+                return res
+
+            res = run()
             # ports_enabled is a static argname → two executables per bucket.
             # Warm the variant the sample did NOT exercise too, so a batch
             # whose port-bearing mix differs from the warm sample doesn't
             # compile inside the measured window.
-            other = dict(common, ports_enabled=not common["ports_enabled"])
-            res_o = self._run_batch_fn(pb, et, self.device.nt, self.device.tc,
-                                       tb, np.int32(0), topo_carry=None, **other)
-            np.asarray(res_o.node_idx)
-            if any(p.spec.volumes for p in warm_slice):
-                # volume workloads dispatch with an extra_mask tensor — a
-                # distinct trace signature; warm it (all-True mask) so the
-                # first PVC batch doesn't compile mid-measure
-                vm = np.ones((bucket, self.device.caps.nodes), bool)
-                res_v = self._run_batch_fn(pb, et, self.device.nt,
-                                           self.device.tc, tb, np.int32(0),
-                                           topo_carry=None,
-                                           **dict(common, extra_mask=vm))
-                np.asarray(res_v.node_idx)
-                if res_v.final_sel_counts is not None:
-                    # the pipelined steady state runs mask+carry — warm that
-                    # trace too (PreemptionPVs compiled it mid-measure)
-                    res_vc = self._run_batch_fn(
-                        pb, et, self.device.nt, self.device.tc, tb,
-                        np.int32(0),
-                        topo_carry=(res_v.final_sel_counts,
-                                    res_v.final_seg_exist),
-                        **dict(common, extra_mask=vm))
-                    np.asarray(res_vc.node_idx)
+            run(ports_enabled=not common["ports_enabled"])
+            # volume workloads dispatch with an extra_mask tensor, claim
+            # workloads with a dra_mask, mixed batches with both: each is a
+            # trace signature of its own, and so is its carry variant, which
+            # the pipelined steady state runs (PreemptionPVs compiled it
+            # mid-measure). Warm each the sample exercises (all-True masks).
+            ones = np.ones((bucket, self.device.caps.nodes), bool)
+            has_vol = any(p.spec.volumes for p in warm_slice)
+            masks = [dict(extra_mask=ones)] if has_vol else []
             if any(p.spec.resource_claims for p in warm_slice):
-                # claim workloads dispatch with a dra_mask tensor — its own
-                # trace signature; warm it (all-True) plus the carry variant
-                # the pipelined steady state runs. A batch can carry BOTH
-                # masks (mixed volume+claim pods): warm that combination too
-                # when the sample has volumes, else the first mixed batch
-                # compiles mid-measure.
-                dm = np.ones((bucket, self.device.caps.nodes), bool)
-                variants = [dict(common, dra_mask=dm)]
-                if any(p.spec.volumes for p in warm_slice):
-                    vm2 = np.ones((bucket, self.device.caps.nodes), bool)
-                    variants.append(dict(common, extra_mask=vm2, dra_mask=dm))
-                for var in variants:
-                    res_d = self._run_batch_fn(pb, et, self.device.nt,
-                                               self.device.tc, tb, np.int32(0),
-                                               topo_carry=None, **var)
-                    np.asarray(res_d.node_idx)
-                    if res_d.final_sel_counts is not None:
-                        res_dc = self._run_batch_fn(
-                            pb, et, self.device.nt, self.device.tc, tb,
-                            np.int32(0),
-                            topo_carry=(res_d.final_sel_counts,
-                                        res_d.final_seg_exist),
-                            **var)
-                        np.asarray(res_dc.node_idx)
+                masks.append(dict(dra_mask=ones))
+                if has_vol:
+                    masks.append(dict(extra_mask=ones, dra_mask=ones))
+            for m in masks:
+                res_m = run(**m)
+                if res_m.final_sel_counts is not None:
+                    run(carry=(res_m.final_sel_counts, res_m.final_seg_exist),
+                        **m)
             warmed += 1
             # time a clean second execution: the calibration sample
             t0 = self.now_fn()
-            res2 = self._run_batch_fn(pb, et, self.device.nt, self.device.tc,
-                                      tb, np.int32(1), topo_carry=None, **common)
-            np.asarray(res2.node_idx)
+            run(key=1)
             timings.append((bucket, self.now_fn() - t0))
             if res.final_sel_counts is not None:
                 # the pipelined path re-traces with a carry: warm it too.
@@ -1961,11 +1694,7 @@ class TPUScheduler(Scheduler):
                 # lazily ahead of the first real batch and hand it a
                 # multi-hundred-ms stall (the p99 tail this warmup exists
                 # to remove).
-                res3 = self._run_batch_fn(
-                    pb, et, self.device.nt, self.device.tc, tb, np.int32(0),
-                    topo_carry=(res.final_sel_counts, res.final_seg_exist),
-                    **common)
-                np.asarray(res3.node_idx)
+                run(carry=(res.final_sel_counts, res.final_seg_exist))
                 warmed += 1
             if self._preemption_wired() and res.static_masks:
                 # failure-path program: the preemption screen compiles on
@@ -1973,10 +1702,9 @@ class TPUScheduler(Scheduler):
                 # only appear mid-measure (Unschedulable) would pay it
                 # inside the window otherwise
                 try:
-                    from ..ops.preempt import screen_prefix
-
-                    pres = screen_prefix(pb, self.device.nt, res.static_masks,
-                                         np.ones(len(warm_slice), bool))
+                    pres = device_step.screen_preemption(
+                        self.device, pb, res.static_masks,
+                        np.ones(len(warm_slice), bool))
                     np.asarray(pres.best)
                     warmed += 1
                 except Exception:  # noqa: BLE001 — warm-only optimization

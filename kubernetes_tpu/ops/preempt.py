@@ -46,9 +46,9 @@ class PreemptResult(NamedTuple):
 
 def screen_prefix(pb, nt, static_masks, failed_prefix):
     """Pad an [n]-bool per-pod failure prefix to pb.capacity and run the
-    screen — the ONE construction every caller (batch commit, wire service,
-    bucket warmup) must share, so a signature or mask-convention change
-    lands everywhere at once.
+    screen — the one construction, called by backend/device_step.py's
+    screen_preemption for the batch commit, the wire service and the
+    bucket warm-up alike.
 
     The padding happens EAGERLY in numpy, outside the jit: the prefix is a
     host-side numpy bool array, and assigning it into a numpy buffer inside

@@ -260,6 +260,3 @@ class Capacities:
     prio_classes: int = 32    # distinct pod priority values (+ reserved row 0)
     superpods: int = 16       # S: torus superpods the grid axis can hold
     sp_slots: int = 16        # P: node positions per superpod torus
-
-    def grow_nodes(self, n: int) -> "Capacities":
-        return dataclasses.replace(self, nodes=round_node_capacity(n, self.nodes))

@@ -193,7 +193,7 @@ def test_batch_sizer_deadline_controller():
     """BatchSizer: a + b·B ≤ deadline over the POP→COMMIT attempt latency,
     clamped to [min, max] and floored to a compile bucket, from EMA
     estimates of fixed and per-pod latency cost."""
-    from kubernetes_tpu.backend.tpu_scheduler import BatchSizer
+    from kubernetes_tpu.backend.sizer import BatchSizer
 
     s = BatchSizer(max_batch=512, deadline_s=0.0)
     assert s.target() == 512  # disabled: always max
@@ -403,7 +403,7 @@ def test_batch_sizer_deadline_bounds_batches():
     """The deadline-based sizer (ON by default, KTPU_BATCH_DEADLINE_MS=500)
     shrinks the target batch when observed cycles are slow, and never below
     min_batch."""
-    from kubernetes_tpu.backend.tpu_scheduler import BatchSizer
+    from kubernetes_tpu.backend.sizer import BatchSizer
 
     sizer = BatchSizer(max_batch=512, deadline_s=0.5)
     for _ in range(20):
