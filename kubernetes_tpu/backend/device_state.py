@@ -49,6 +49,29 @@ def _apply_rows(nt: NodeTensors, slots: jax.Array, updates: dict,
 _apply_rows_jit = jax.jit(_apply_rows)
 
 
+def _release_rows(requested: jax.Array, nonzero: jax.Array,
+                  class_req: jax.Array, sel_counts: Optional[jax.Array],
+                  slots: jax.Array, req: jax.Array, nz: jax.Array,
+                  cls: jax.Array, sig: Optional[jax.Array]):
+    """Subtract released pods' rows from the carry, jitted: ``req``/``nz``
+    [B, R] at ``slots`` [B], ``req`` again at (slot, class ``cls``), and
+    with a topology carry its [S, N] counts by the pods' [B, S] signature
+    rows. Padding rows are zeros at slot 0; a slot may repeat (adds
+    accumulate)."""
+    out = (requested.at[slots].add(-req), nonzero.at[slots].add(-nz),
+           class_req.at[slots, cls].add(-req))
+    if sel_counts is None:
+        return out + (None,)
+    return out + (sel_counts.at[:, slots].add(-sig.T),)
+
+
+_release_rows_jit = jax.jit(_release_rows)
+
+# a row value at the int32 clamp no longer says what it sums: a release
+# there cannot be subtracted exactly
+_CLAMP = np.int32(2 ** 31 - 1)
+
+
 def _bucket(n: int, floor: int = 8) -> int:
     """Next power of two ≥ n (≥ floor) — the static-shape recompile guard."""
     b = floor
@@ -155,6 +178,10 @@ class DeviceState:
         # reserved for membership/zone changes (snapshot.py changed_names).
         self._recon_pending: set = set()
         self._seen_struct: int = -1
+        # the topology carry the newest dispatch evolved ((sel_counts,
+        # seg_exist) futures, None without topology): the pipelined next
+        # batch starts from it, less the pods released since
+        self.topo_carry = None
 
     @property
     def tc(self):
@@ -624,6 +651,8 @@ class DeviceState:
         the host commits this one."""
         import dataclasses as _dc
 
+        self.topo_carry = (None if result.final_sel_counts is None else
+                           (result.final_sel_counts, result.final_seg_exist))
         if result.final_requested is None:
             return
         updates = dict(
@@ -664,6 +693,128 @@ class DeviceState:
             for pid in port_ids[i]:
                 if pid > 0:
                     self._mirror["port_bits"][slot, pid >> 5] |= np.uint32(1) << np.uint32(pid & 31)
+
+    def release(self, removals) -> Tuple[int, int]:
+        """Put deleted pods' requests back on the carry: one scatter that
+        subtracts each pod's request rows from the device's requested,
+        nonzero_requested and class_req (and its signature counts from the
+        topology carry), and the same from the host mirror, so the next
+        sync or reconcile finds the row equal to the cache's. Never blocks:
+        the device arrays may still be futures.
+
+        ``removals``: (pod, cache.Removal) pairs. A pod is subtracted only
+        where this row is known to hold it: the row was last uploaded or
+        reconciled at a generation in [added_gen, removed_gen). A row
+        uploaded after the removal no longer holds the pod, and the pod is
+        skipped. A row confirmed before the add may or may not hold it (a
+        dispatch adopts its own placements onto the carry before their
+        commit is reconciled), so it is inexact. Returns (pods subtracted,
+        rows that cannot be subtracted exactly — their uploaded generation
+        is dropped, so the next sync repairs them)."""
+        names: List[str] = []
+        slots: List[int] = []
+        rows: List[Tuple[np.ndarray, np.ndarray]] = []
+        classes: List[int] = []
+        pods: List = []
+        inexact = 0
+        for pod, rm in removals:
+            slot = self.encoder.node_slots.get(rm.node)
+            up = self._uploaded_gen.get(rm.node)
+            if slot is None or up is None or up >= rm.removed_gen:
+                continue
+            row = None
+            if up >= rm.added_gen:
+                try:
+                    row = self.encoder.pod_request_rows(pod)
+                    cid = self.encoder.prio_class_id(pod.spec.priority)
+                except CapacityError:
+                    row = None
+            if row is None:
+                self._uploaded_gen.pop(rm.node, None)
+                inexact += 1
+                continue
+            names.append(rm.node)
+            slots.append(slot)
+            rows.append(row)
+            classes.append(cid)
+            pods.append(pod)
+        if not slots:
+            return 0, inexact
+        mirror = self._mirror
+        slot_arr = np.asarray(slots, np.int32)
+        req = np.stack([r for r, _ in rows])
+        nz = np.stack([z for _, z in rows])
+        cls = np.asarray(classes, np.int32)
+        bad = ((req >= _CLAMP).any(1) | (nz >= _CLAMP).any(1)
+               | (mirror["requested"][slot_arr] >= _CLAMP).any(1)
+               | (mirror["nonzero_requested"][slot_arr] >= _CLAMP).any(1))
+        if bad.any():
+            for i in np.nonzero(bad)[0]:
+                self._uploaded_gen.pop(names[i], None)
+            inexact += int(bad.sum())
+            keep = ~bad
+            slot_arr, req, nz, cls = slot_arr[keep], req[keep], nz[keep], cls[keep]
+            pods = [p for p, k in zip(pods, keep) if k]
+            if not pods:
+                return 0, inexact
+        np.subtract.at(mirror["requested"], slot_arr, req)
+        np.subtract.at(mirror["nonzero_requested"], slot_arr, nz)
+        np.subtract.at(mirror["class_req"], (slot_arr, cls), req)
+        n = len(pods)
+        b = _bucket(n)
+        pad = b - n
+        slot_arr = np.pad(slot_arr, (0, pad))
+        req = np.pad(req, ((0, pad), (0, 0)))
+        nz = np.pad(nz, ((0, pad), (0, 0)))
+        cls = np.pad(cls, (0, pad))
+        sel = sig = None
+        if self.topo_carry is not None and self.sig_table.n_sigs > 1:
+            sig = np.zeros((b, self.caps.sigs), np.int32)
+            for i, pod in enumerate(pods):
+                sig[i] = self.sig_table.pod_sig_mask(pod)
+            if sig.any():
+                sel = self.topo_carry[0]
+            else:
+                sig = None
+        nt = self.nt
+        args = (nt.requested, nt.nonzero_requested, nt.class_req, sel,
+                slot_arr, req, nz, cls, sig)
+        from . import telemetry
+
+        with telemetry.dispatch("release_rows", bucket=str(b)):
+            requested, nonzero, class_req, sel_out = _release_rows_jit(*args)
+        telemetry.cost_probe("release_rows", str(b), _release_rows_jit, args)
+        import dataclasses as _dc
+
+        self.nt = _dc.replace(nt, requested=requested,
+                              nonzero_requested=nonzero, class_req=class_req)
+        if sel_out is not None:
+            self.topo_carry = (sel_out, self.topo_carry[1])
+        return n, inexact
+
+    def warm_release(self, max_pods: int) -> None:
+        """Compile the release scatter at every bucket up to ``max_pods``
+        released at once (and its topology-carry variant while signatures
+        are registered): zero rows at slot 0, results dropped."""
+        nt = self.nt
+        sels = [None]
+        if self.sig_table.n_sigs > 1:
+            sels.append(self.tc.sel_counts)
+        b = _bucket(1)
+        while True:
+            for sel in sels:
+                sig = (None if sel is None
+                       else np.zeros((b, self.caps.sigs), np.int32))
+                out = _release_rows_jit(
+                    nt.requested, nt.nonzero_requested, nt.class_req, sel,
+                    np.zeros(b, np.int32),
+                    np.zeros((b, self.caps.resources), np.int32),
+                    np.zeros((b, self.caps.resources), np.int32),
+                    np.zeros(b, np.int32), sig)
+                np.asarray(out[0])  # land the compile
+            if b >= max_pods:
+                return
+            b *= 2
 
     def _track_images(self, name: str, ni: Optional[NodeInfo]) -> bool:
         """Maintain global image num-node counts (first-seen size wins,

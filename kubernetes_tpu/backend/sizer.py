@@ -3,9 +3,9 @@
 Originally the in-process ring's controller (backend/tpu_scheduler.py);
 moved here when the wire path gained the same multi-batch in-flight shape
 (WireScheduler's pipelined transport) — the controlled quantities are
-identical on both: the pop→commit attempt latency the iso-p99 contract is
-defined over, and the blocked residual at the point results are claimed
-(device commit-wait in process, reply claim-wait on the wire).
+identical on both: a batch's own latency (its ring dwell left out), and the
+blocked residual at the point results are claimed (device commit-wait in
+process, reply claim-wait on the wire).
 """
 
 from __future__ import annotations
@@ -69,12 +69,13 @@ class BatchSizer:
     """Deadline-based batch cutting (SURVEY §7 hard-part 7: iso-p99 needs
     the batch size bounded by a latency budget, not just throughput).
 
-    The controlled quantity is the POP→COMMIT attempt latency itself — the
-    histogram BASELINE.md's iso-p99 is defined over — observed per landed
-    batch at the commit site (it spans the batch's own dispatch plus the
-    overlapped next cycle; modeling raw cycle time instead systematically
-    underestimates, because a batch's async device execution lands in the
-    NEXT cycle's commit wait). Latency is modeled as ``a + b·B`` via an
+    The controlled quantity is the latency of a batch's OWN stages,
+    observed per landed batch at the commit site: in process its pop to
+    dispatch plus its commit, on the wire its submit to claim. Both hold the
+    batch's async device execution (it lands in the commit or claim wait)
+    and leave out its dwell behind other batches in the ring, which the
+    ring depth sets and which fed into the fit reads as per-pod cost.
+    Latency is modeled as ``a + b·B`` via an
     exponentially-decayed least-squares fit over (B, span) observations;
     the target batch is the largest B with ``a + b·B ≤ deadline ·
     _P99_HEADROOM`` — the headroom (0.6) keeps the OBSERVED p99 (slow

@@ -117,6 +117,7 @@ PROGRAM_NAMES = frozenset({
     "claim_mask",       # DRA claim feasibility screen
     "preempt_screen",   # preemption victim screen
     "apply_rows",       # device-state row upload kernel
+    "release_rows",     # deleted pods' requests taken off the carry
     # ledger-only program: client-side attribution of a wire batch (the
     # record is fed from the server's echoed deviceTime, not a local jit)
     "wire_schedule_batch",

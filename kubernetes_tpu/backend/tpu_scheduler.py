@@ -24,7 +24,7 @@ import contextlib
 import dataclasses
 import os
 from collections import deque
-from typing import Callable, Deque, Dict, List, Optional, Set
+from typing import Callable, Deque, Dict, List, Optional, Set, Tuple
 
 import jax
 import numpy as np
@@ -151,10 +151,11 @@ class TPUScheduler(Scheduler):
         self.device_fault_fn: Optional[Callable[[str], Optional[BaseException]]] = None
         if batch_deadline_ms is None:
             # ON by default (VERDICT r3 item 4): the iso-p99 contract needs
-            # pop→commit bounded, so the sizer cuts batches to fit ~2 cycles
-            # in the deadline. 500ms keeps ≥90% of uncapped throughput on
-            # the CPU fallback (scan step ~1.8ms/pod) and never binds on
-            # accelerators (per-pod cost far below the budget). "0" disables.
+            # pop→commit bounded, so the sizer cuts batches to fit each
+            # batch's own stages in the deadline. 500ms keeps ≥90% of
+            # uncapped throughput on the CPU fallback (scan step ~1.8ms/pod)
+            # and never binds on accelerators (per-pod cost far below the
+            # budget). "0" disables.
             batch_deadline_ms = float(os.environ.get("KTPU_BATCH_DEADLINE_MS", "500"))
         self.sizer = BatchSizer(batch_size, batch_deadline_ms / 1000.0)
         # device/host comparer (SURVEY.md §5.2 mapping of the cache drift
@@ -180,22 +181,6 @@ class TPUScheduler(Scheduler):
         # adaptive-sampling rotation start: a device scalar chained from the
         # previous batch's evolved carry (schedule_one.go:475 rotation)
         self._start_carry = None
-        # async pipeline (SURVEY §2.7 P3 analog), generalized to a bounded
-        # multi-batch in-flight RING: up to ``pipeline_depth`` dispatched
-        # batches ride the device at once (oldest commits first), so the
-        # host work of landing batch k overlaps the device execution of
-        # k+1..k+K instead of just the dispatch of k+1. KTPU_PIPELINE=0
-        # forces the synchronous path; KTPU_PIPELINE_DEPTH sets K (default
-        # 2 — deeper rings add pop→commit latency per batch, which the
-        # deadline sizer then pays for in smaller batches).
-        if os.environ.get("KTPU_PIPELINE", "1") == "0":
-            self.pipeline_depth = 0
-        else:
-            # depth 0 is a valid setting: synchronous, same as KTPU_PIPELINE=0
-            self.pipeline_depth = max(0, int(os.environ.get(
-                "KTPU_PIPELINE_DEPTH", "2")))
-        self._inflight: Deque[_Inflight] = deque()
-        self.pipelined_batches = 0
         # ---- commit data plane (backend/commit_plane.py) ----
         # The commit WORKER lands ring-overflow batches on its own thread,
         # overlapping batch K's host commit with batch K+1's encode/
@@ -216,6 +201,25 @@ class TPUScheduler(Scheduler):
             want_worker = worker_env == "1"
         else:
             want_worker = jax.default_backend() != "cpu"
+        # async pipeline (SURVEY §2.7 P3 analog), generalized to a bounded
+        # multi-batch in-flight RING: up to ``pipeline_depth`` dispatched
+        # batches ride the device at once (oldest commits first), so the
+        # host work of landing batch k overlaps the device execution of
+        # k+1..k+K instead of just the dispatch of k+1. KTPU_PIPELINE=0
+        # forces the synchronous path; KTPU_PIPELINE_DEPTH sets K. Each
+        # ring slot adds a cycle of dwell to every batch's pop→commit
+        # (which the deadline sizer leaves out of what it controls). Inline
+        # the default K is 2. With the commit worker it is 1: the worker's
+        # own queue overlaps the commits, and the ring only has to hold the
+        # newest batch, whose adopted carry the next encode chains on.
+        if os.environ.get("KTPU_PIPELINE", "1") == "0":
+            self.pipeline_depth = 0
+        else:
+            # depth 0 is a valid setting: synchronous, same as KTPU_PIPELINE=0
+            self.pipeline_depth = max(0, int(os.environ.get(
+                "KTPU_PIPELINE_DEPTH", "1" if want_worker else "2")))
+        self._inflight: Deque[_Inflight] = deque()
+        self.pipelined_batches = 0
         if self.pipeline_depth and want_worker:
             from .commit_plane import CommitWorker
 
@@ -235,6 +239,16 @@ class TPUScheduler(Scheduler):
         # changes, so the worker mode gates on events instead.
         self._chain_ext_seq = -1
         self._chain_dirty = False
+        # a deleted bound pod is no external change when the carry can take
+        # its requests back exactly: its (pod, cache.Removal) pair waits
+        # here for the next dispatch, drain-path sync or commit reconcile,
+        # which subtracts it (DeviceState.release). Recorded under the cache
+        # lock, so every removal lands wholly before or after a snapshot the
+        # releases are taken with. A deletion the carry cannot take back
+        # bumps the external sequence; (the sequence number that bump made,
+        # its reason) names a chain break at exactly that number.
+        self._releases: List[tuple] = []
+        self._blocked: Tuple[int, Optional[str]] = (-1, None)
         # volume-bindability pre-pass (ops/volume_mask.py): lets PVC-bearing
         # pods ride the batched path with a [P, N] static screen + exact
         # host verify of the chosen node at commit (VERDICT r4 item 4)
@@ -558,10 +572,14 @@ class TPUScheduler(Scheduler):
         t0 = self.now_fn()
         t_pop = t_pop if t_pop is not None else t0
         mutex = self.commit_plane.device_mutex
-        with tracing.span("device.encode.pipelined", batch=len(batched)):
+        with tracing.span("device.encode.pipelined",
+                          batch=len(batched)) as sp:
             with mutex:
-                enc = self._try_pipelined_encode(batched)
+                enc, outcome, released = self._try_pipelined_encode(batched)
                 device = self.device  # instance the encode ran against
+            if sp is not None:
+                sp.attributes.update(chain=outcome, released=released)
+        self.smetrics.carry_chain_batches.inc(outcome)
         if enc is not None:
             pb, et, tb, extra_mask, dra_mask = enc
             t_sync = t0  # nothing to upload: the in-flight carry IS the state
@@ -570,15 +588,24 @@ class TPUScheduler(Scheduler):
             # own); only sync+encode below belong to THIS batch's spans
             self._drain_inflight()
             self._ensure_device()  # the drain's commit may have killed it
-            # carry-gate baseline: capture BEFORE the snapshot update — an
-            # external event racing in after this reads as a changed seq on
-            # the next pipelined probe (conservative break, never a miss)
-            ext_seq = self.external_change_seq()
-            self.cache.update_snapshot(self.snapshot)
+            # carry-gate baseline, the releases and the snapshot in one
+            # cache critical section: an event racing in after it reads as
+            # a changed seq (or a pending release) on the next pipelined
+            # probe, and every taken release is in the snapshot
+            with self.cache.locked():
+                ext_seq = self.external_change_seq()
+                taken = self._take_releases()
+                self.cache.update_snapshot(self.snapshot)
 
             def sync_encode():
                 with mutex:
-                    with tracing.span("device.sync"):
+                    with tracing.span("device.sync") as sync_sp:
+                        # releases first: the rows they free then diff
+                        # equal to the snapshot and upload nothing
+                        n_rel, _ = self._apply_releases(taken)
+                        taken.clear()  # a retry's grown mirror syncs afresh
+                        if sync_sp is not None:
+                            sync_sp.attributes["released"] = n_rel
                         self.device.sync(self.snapshot)
                     self._sync_slot_reuse_metric()
                     t_sync = self.now_fn()
@@ -609,14 +636,6 @@ class TPUScheduler(Scheduler):
         # dispatches, and the host waits on both, per batch)
         key = np.int32(self.batch_counter)
         prev = self._inflight[-1] if self._inflight else None
-        # cross-batch topology carry: batch k+1 starts from the NEWEST
-        # in-flight batch's evolved sel_counts/seg_exist instead of the
-        # (stale, pre-k) host tables — the ring chains carries end to end.
-        # Only valid on the pipelined path — after a drain the host recounts
-        # and device.tc is the truth again (prev is None then).
-        carry = None
-        if prev is not None and prev.result.final_sel_counts is not None:
-            carry = (prev.result.final_sel_counts, prev.result.final_seg_exist)
         sample = device_step.sample_window(self.percentage_of_nodes_to_score,
                                            self.cache.node_count(),
                                            self._start_carry)
@@ -634,6 +653,13 @@ class TPUScheduler(Scheduler):
                             pod_cycle)
                 return
             host_pb = device.encoder.last_host_pb
+            # cross-batch topology carry: batch k+1 starts from the NEWEST
+            # dispatch's evolved sel_counts/seg_exist (less the pods
+            # released since) instead of the (stale, pre-k) host tables —
+            # the ring chains carries end to end. Only valid on the
+            # pipelined path — after a drain the host recounts and device.tc
+            # is the truth again (prev is None then).
+            carry = device.topo_carry if prev is not None else None
             mode_info = self._topo_mode_info()
             topo_mode, vd_bucket, host_key = mode_info
             telemetry.event("encode", batchId=batch_id, bucket=bucket,
@@ -708,7 +734,9 @@ class TPUScheduler(Scheduler):
             if self.pipeline_depth:
                 self.pipelined_batches += 1
             if self.commit_worker is not None:
-                backlog = max(1, self.pipeline_depth)
+                # two at least: with a one-slot ring the worker still holds
+                # one batch's commit while the next one waits its turn
+                backlog = max(2, self.pipeline_depth)
                 if self.commit_worker.depth() >= backlog:
                     with tracing.span("device.commit.backpressure"):
                         t_bp = self.now_fn()
@@ -724,18 +752,20 @@ class TPUScheduler(Scheduler):
         dur.observe(t_dispatch - t_enc, "compute")
         self.smetrics.device_batch_size.observe(len(batched))
         # (the sizer's latency observations are fed at the commit site,
-        # where the batch's true pop→commit span is known)
+        # where the batch's own stages are known)
 
     def _try_pipelined_encode(self, batched: List[QueuedPodInfo]):
         """Encode the next batch for dispatch directly on the in-flight
         batch's adopted device carry — legal only when (a) nothing external
         touched the cluster since the in-flight dispatch and (b) encoding
         registers no new signature/term (a fresh row is backfilled from host
-        counts that cannot see the in-flight commits). Returns _encode's
-        tuple, or None to take the drain+sync path. Caller holds the device
+        counts that cannot see the in-flight commits). Deletions since the
+        last dispatch go back on the carry first. Returns (_encode's tuple
+        or None to take the drain+sync path, the outcome: "kept" or why the
+        chain broke, pods released onto the carry). Caller holds the device
         mutex."""
         if not self.pipeline_depth or not self._inflight or self.device is None:
-            return None
+            return None, "no_ring", 0
         if self.commit_worker is not None:
             # async-commit mode: the worker's own in-progress commits dirty
             # the cache, so the has_dirty walk below cannot tell them from
@@ -743,31 +773,95 @@ class TPUScheduler(Scheduler):
             # any external node-truth event since the chain's last full
             # sync, or a host-rejected commit (device row invalidated),
             # breaks the chain — both strictly conservative.
-            if (self._chain_dirty
-                    or self.external_change_seq() != self._chain_ext_seq):
-                return None
+            if self._chain_dirty:
+                return None, "dirty", 0
+            seq = self.external_change_seq()
+            if seq != self._chain_ext_seq:
+                at, reason = self._blocked
+                return None, (reason if at == seq and reason
+                              else "external"), 0
             if any(qp.pod.spec.volumes for qp in batched):
                 # the volume prescreen reads self.snapshot, which must not
                 # be refreshed while the worker's commit tail may be
                 # reading it — PVC batches take the drain+sync path
-                return None
+                return None, "volumes", 0
         else:
             self.cache.update_snapshot(self.snapshot)
             if self.device.has_dirty(self.snapshot):
-                return None  # external change breaks the device-carry chain
+                # external change or deletion breaks the device-carry chain
+                return None, "dirty", 0
+        with self.cache.locked():
+            taken = self._take_releases()
+        released, inexact = self._apply_releases(taken)
+        if inexact:
+            # a row the release could not subtract still holds its pod
+            return None, "inexact", released
         st = self.device.sig_table
         vocab0 = (st.n_sigs, st.n_terms)
         try:
             enc = self._encode(batched)
         except CapacityError:
-            return None  # grow via the drain+sync path (idempotent re-encode)
+            # grow via the drain+sync path (idempotent re-encode)
+            return None, "capacity", released
         if (st.n_sigs, st.n_terms) != vocab0:
-            return None
+            return None, "vocab", released
         if self._topo_mode_info() != self._inflight[-1].mode_info:
             # the carry shapes (seg_exist vs term_cnt, vd bucket) differ —
             # land the in-flight batches and restart the chain on host truth
-            return None
-        return enc
+            return None, "mode", released
+        return enc, "kept", released
+
+    # ------------------------------------------------------- carry releases
+
+    @staticmethod
+    def _release_blocker(pod: Pod) -> Optional[str]:
+        """Why the carry cannot take a deleted bound pod's requests back
+        exactly (None: it can). Host ports set bits another pod may share;
+        volume and claim bookkeeping and (anti-)affinity terms live in
+        tables the carry keeps no per-pod count of."""
+        if pod.host_ports():
+            return "ports"
+        if pod.spec.volumes:
+            return "pvcs"
+        if pod.spec.resource_claims:
+            return "claims"
+        a = pod.spec.affinity
+        if a is not None and (a.pod_affinity is not None
+                              or a.pod_anti_affinity is not None):
+            return "topology"
+        return None
+
+    def _remove_bound_pod(self, pod: Pod) -> None:
+        """A completed (or evicted) bound pod: its removal from the cache
+        and its release, recorded together under the cache lock. A pod the
+        carry cannot take back breaks the chain as an external change."""
+        blocker = self._release_blocker(pod)
+        with self.cache.locked():
+            removal = self.cache.remove_pod(pod)
+            if blocker is None and removal is not None:
+                self._releases.append((pod, removal))
+                return
+            self._blocked = (self._bump_external(), blocker)
+
+    def _take_releases(self) -> List[tuple]:
+        """The pending releases, emptied; caller holds the cache lock."""
+        taken, self._releases = self._releases, []
+        return taken
+
+    def _apply_releases(self, taken: List[tuple]):
+        """Subtract ``taken`` from the device carry and mirror; caller holds
+        the device mutex. Returns (pods released, rows it could not
+        subtract exactly — invalidated, which breaks the chain). Without a
+        device there is nothing to subtract: the next one syncs from the
+        cache, which no longer holds the pods."""
+        if not taken or self.device is None:
+            return 0, 0
+        released, inexact = self.device.release(taken)
+        if released:
+            self.smetrics.carry_released_pods.inc(value=released)
+        if inexact:
+            self._chain_dirty = True
+        return released, inexact
 
     def _encode(self, batched: List[QueuedPodInfo]):
         """(pb, et, tb, extra_mask, dra_mask) at the batch's sizer bucket,
@@ -912,13 +1006,21 @@ class TPUScheduler(Scheduler):
             if self.device is not None:
                 with tracing.span("device.commit.reconcile",
                                   batch=len(fl.qps),
-                                  worker="commit" if on_worker else "inline"):
+                                  worker="commit" if on_worker
+                                  else "inline") as rec_sp:
                     t_rec0 = self.now_fn()
                     snap = (self._commit_snapshot if on_worker
                             else self.snapshot)
                     with mutex:
-                        self.cache.update_snapshot(snap)
+                        # the deletions this snapshot holds go back on the
+                        # carry first, so their rows compare equal
+                        with self.cache.locked():
+                            taken = self._take_releases()
+                            self.cache.update_snapshot(snap)
+                        n_rel, _ = self._apply_releases(taken)
                         left = self.device.reconcile(snap)
+                    if rec_sp is not None:
+                        rec_sp.attributes["released"] = n_rel
                     if left:
                         self._chain_dirty = True
                     self.smetrics.device_batch_duration.observe(
@@ -935,6 +1037,9 @@ class TPUScheduler(Scheduler):
             with mutex:
                 self.device = None  # full rebuild on next _ensure_device
             self._start_carry = None  # dead-backend future
+            # the rebuilt device starts its chain from a drain+sync, never
+            # from ring stragglers of the dead one
+            self._chain_dirty = True
             if self.commit_worker is not None:
                 stale = self.commit_worker.steal_pending()
             else:
@@ -955,14 +1060,21 @@ class TPUScheduler(Scheduler):
             telemetry.sample_hbm()
         self.smetrics.pipeline_inflight.set(value=len(self._inflight))
         self.smetrics.device_batch_duration.observe(self.now_fn() - t0, "commit")
-        # the sizer controls the POP→COMMIT attempt latency: observe it here,
-        # where this batch's span just completed (fl.t0 = its pop time). The
+        # the sizer controls the latency of the batch's OWN stages: its pop
+        # to dispatch on the scheduling thread (fl.t0 = its pop time) plus
+        # this commit, device wait included. Its dwell in the ring and in
+        # the worker's queue is left out: that is a count of batches set by
+        # the ring depth and the worker's backlog, each batch's own stages
+        # bounded by this same target — fed into the a+b·B fit it reads as
+        # per-pod cost and halves the batch without shortening the dwell
+        # (the wire path feeds its service time for the same reason). The
         # size fed is the BUCKET (padded program length) — that is what the
         # latency actually tracks. The commit-wait residual feeds the stall
         # model, which caps the bucket where device time outruns the
         # overlapped host window.
         bucket = self.sizer.bucket_for(len(fl.qps))
-        self.sizer.update(bucket, self.now_fn() - fl.t0)
+        own = max(fl.t_submit - fl.t0, 0.0) + (self.now_fn() - t0)
+        self.sizer.update(bucket, own)
         if wait is not None:
             self.sizer.update_wait(bucket, wait)
 
@@ -1709,18 +1821,20 @@ class TPUScheduler(Scheduler):
                     warmed += 1
                 except Exception:  # noqa: BLE001 — warm-only optimization
                     pass
+        # completions put pods back on the carry at any count up to a
+        # couple of batches' worth between two dispatches
+        self.device.warm_release(2 * self.batch_size)
         self._calibrate_sizer(timings)
         return warmed
 
     def _calibrate_sizer(self, timings) -> None:
         """Seed the BatchSizer's latency model from the warm runs' measured
         per-bucket execution times (least squares on exec(B) = ea + eb·B).
-        The pop→commit latency of a pipelined batch spans its own execution
-        plus the ring's worth of batches dispatched after it, so the seed is
-        a ≈ (K+1)·ea + host overhead, b ≈ (K+1)·eb for ring depth K. Without
-        this the model starts from blind seeds and the first dozen measured
-        batches are spent oscillating through buckets (each flip breaking
-        the pipelined carry chain)."""
+        A batch's own stages (what the commit site feeds the sizer) hold
+        its execution once, so the seed is a ≈ ea + host overhead, b ≈ eb.
+        Without this the model starts from blind seeds and the first dozen
+        measured batches are spent oscillating through buckets (each flip
+        breaking the pipelined carry chain)."""
         if len(timings) < 2:
             return
         xs = np.array([float(b) for b, _ in timings])
@@ -1728,10 +1842,9 @@ class TPUScheduler(Scheduler):
         eb, ea = np.polyfit(xs, ys, 1)
         if eb <= 0:
             return
-        span = self.pipeline_depth + 1
         s = self.sizer
-        s._a = max(span * ea, 0.0) + 0.03
-        s._b = span * eb
+        s._a = max(ea, 0.0) + 0.03
+        s._b = eb
         s.updates = max(s.updates, 3)
         s._outliers = 0
         # the warm runs time EXECUTION directly (idle host): seed the stall

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 from ..api.types import Node, Pod
 from ..framework.types import NodeInfo, next_generation
@@ -39,6 +39,19 @@ class _PodState:
     assumed: bool = False
     binding_finished: bool = False
     deadline: Optional[float] = None
+    # the node generation the add of this pod gave its NodeInfo: a snapshot
+    # clone at a generation in [added_gen, the removal's) holds the pod
+    added_gen: int = 0
+
+
+class Removal(NamedTuple):
+    """What ``remove_pod`` took off a node: the node, and the node
+    generations that added and removed the pod. A view of the node cloned
+    at generation g holds the pod iff added_gen <= g < removed_gen."""
+
+    node: str
+    added_gen: int
+    removed_gen: int
 
 
 class Cache:
@@ -74,8 +87,8 @@ class Cache:
         if key in self.pod_states:
             raise KeyError(f"pod {key} already in cache")
         pod.spec.node_name = node_name
-        self._add_pod_to_node(pod, node_name)
-        self.pod_states[key] = _PodState(pod=pod, assumed=True)
+        gen = self._add_pod_to_node(pod, node_name)
+        self.pod_states[key] = _PodState(pod=pod, assumed=True, added_gen=gen)
         self._assumed.add(key)
 
     def _finish_locked(self, pod: Pod) -> None:
@@ -113,17 +126,18 @@ class Cache:
         with self._lock:
             ps = self.pod_states.get(key)
             if ps is not None and ps.assumed:
+                gen = ps.added_gen
                 if ps.pod.spec.node_name != pod.spec.node_name:
                     # scheduled elsewhere than assumed: relocate
                     self._remove_pod_from_node(ps.pod, ps.pod.spec.node_name)
-                    self._add_pod_to_node(pod, pod.spec.node_name)
-                self.pod_states[key] = _PodState(pod=pod)
+                    gen = self._add_pod_to_node(pod, pod.spec.node_name)
+                self.pod_states[key] = _PodState(pod=pod, added_gen=gen)
                 self._assumed.discard(key)
                 return
             if ps is not None:
                 return  # duplicate add
-            self._add_pod_to_node(pod, pod.spec.node_name)
-            self.pod_states[key] = _PodState(pod=pod)
+            gen = self._add_pod_to_node(pod, pod.spec.node_name)
+            self.pod_states[key] = _PodState(pod=pod, added_gen=gen)
 
     def update_pod(self, old: Pod, new: Pod) -> None:
         with self._lock:
@@ -132,15 +146,26 @@ class Cache:
                 self.add_pod(new)
                 return
             self._remove_pod_from_node(ps.pod, ps.pod.spec.node_name)
-            self._add_pod_to_node(new, new.spec.node_name)
-            self.pod_states[old.key()] = _PodState(pod=new)
+            gen = self._add_pod_to_node(new, new.spec.node_name)
+            self.pod_states[old.key()] = _PodState(pod=new, added_gen=gen)
 
-    def remove_pod(self, pod: Pod) -> None:
+    def remove_pod(self, pod: Pod) -> Optional[Removal]:
+        """Drop a pod from the cache; returns the Removal it made, or None
+        when no node held the pod."""
         with self._lock:
             ps = self.pod_states.pop(pod.key(), None)
             self._assumed.discard(pod.key())
-            if ps is not None:
-                self._remove_pod_from_node(ps.pod, ps.pod.spec.node_name)
+            if ps is None:
+                return None
+            node = ps.pod.spec.node_name
+            gen = self._remove_pod_from_node(ps.pod, node)
+            return Removal(node, ps.added_gen, gen) if gen else None
+
+    def locked(self):
+        """The cache's (re-entrant) lock: a caller that keeps bookkeeping of
+        its own beside pod removals and snapshot updates holds it across
+        both, so each removal lands wholly before or after a snapshot."""
+        return self._lock
 
     def apply_batch(self, ops) -> List[Optional[Exception]]:
         """Batched pod-state transitions — the cache half of the commit data
@@ -235,18 +260,26 @@ class Cache:
             self.nodes[node_name] = ni
         return ni
 
-    def _add_pod_to_node(self, pod: Pod, node_name: str) -> None:  # ktpu: locked
-        if node_name:
-            self._node_info(node_name).add_pod(pod)
-            self._dirty.add(node_name)
-            self._removed.discard(node_name)
-            prio = pod.spec.priority
-            self._prio_counts[prio] = self._prio_counts.get(prio, 0) + 1
+    def _add_pod_to_node(self, pod: Pod, node_name: str) -> int:  # ktpu: locked
+        """Returns the generation the add gave the node (0: no node)."""
+        if not node_name:
+            return 0
+        ni = self._node_info(node_name)
+        ni.add_pod(pod)
+        self._dirty.add(node_name)
+        self._removed.discard(node_name)
+        prio = pod.spec.priority
+        self._prio_counts[prio] = self._prio_counts.get(prio, 0) + 1
+        return ni.generation
 
-    def _remove_pod_from_node(self, pod: Pod, node_name: str) -> None:  # ktpu: locked
+    def _remove_pod_from_node(self, pod: Pod, node_name: str) -> int:  # ktpu: locked
+        """Returns the generation the removal gave the node (0: the node
+        did not hold the pod)."""
         ni = self.nodes.get(node_name)
+        gen = 0
         if ni is not None:
-            ni.remove_pod(pod)
+            if ni.remove_pod(pod):
+                gen = ni.generation
             prio = pod.spec.priority
             left = self._prio_counts.get(prio, 0) - 1
             if left > 0:
@@ -258,6 +291,7 @@ class Cache:
                 self.nodes.pop(node_name, None)
                 self._dirty.discard(node_name)
                 self._removed.add(node_name)
+        return gen
 
     # ------------------------------------------------------------- snapshot
 

@@ -337,6 +337,21 @@ class SchedulerMetrics:
             "Tombstoned DeviceState row slots handed to new nodes "
             "(bounded-capacity churn instead of monotonic growth).",
         ))
+        # device-carry chain (backend/tpu_scheduler.py): per pipelined
+        # encode attempt, whether the batch rode the in-flight carry or why
+        # it took the drain+sync path; and the deleted pods whose requests
+        # went back on the carry instead of breaking it
+        self.carry_chain_batches = r.register(Counter(
+            "scheduler_carry_chain_batches_total",
+            "Pipelined encode attempts by outcome: kept, or the reason the "
+            "device-carry chain broke.",
+            ["outcome"],
+        ))
+        self.carry_released_pods = r.register(Counter(
+            "scheduler_carry_released_pods_total",
+            "Deleted bound pods whose requests were subtracted from the "
+            "device carry.",
+        ))
         # commit data plane (backend/commit_plane.py): per-batch commit
         # engine stage latencies (assume → bind → finish → notify →
         # post_bind, plus the whole-batch total) and the notification /

@@ -566,14 +566,20 @@ class ClusterEncoder:
         except TypeError:  # unhashable field value: just skip caching
             return None
 
-    def _build_template(self, pod: Pod) -> _PodTemplate:
-        caps = self.caps
-        kb = _KeyBuilder()
-
+    def pod_request_rows(self, pod: Pod) -> Tuple[np.ndarray, np.ndarray]:
+        """(req, nonzero req) as the resource rows a placement of ``pod``
+        adds to its node's requested / nonzero_requested, pod count 1."""
         r = dict(pod.resource_request())  # copy: resource_request() is cached
         r[resource_api.PODS] = 1
         nz = nonzero_request(pod.resource_request())
         nz[resource_api.PODS] = 1
+        return self.resource_vec(r), self.resource_vec(nz)
+
+    def _build_template(self, pod: Pod) -> _PodTemplate:
+        caps = self.caps
+        kb = _KeyBuilder()
+
+        req, nzreq = self.pod_request_rows(pod)
 
         tols = pod.spec.tolerations
         if len(tols) > caps.tolerations:
@@ -641,8 +647,8 @@ class ClusterEncoder:
 
         return _PodTemplate(
             priority=pod.spec.priority,
-            req=self.resource_vec(r),
-            nzreq=self.resource_vec(nz),
+            req=req,
+            nzreq=nzreq,
             tol_key=tol_key, tol_val=tol_val, tol_op=tol_op,
             tol_effect=tol_effect, tol_prefer=tol_prefer,
             tolerates_unsched=any(t.tolerates(_UNSCHEDULABLE_TAINT) for t in tols),

@@ -364,20 +364,24 @@ class Scheduler:
             elif self._responsible_for(new):
                 self.queue.update(old, new)
         elif event == DELETED:
-            if old is not None and old.spec.node_name:
-                self._bump_external()
             if old is not None:
                 self.smetrics.clear_unschedulable(old.key())
                 # quota release first: the POD_DELETE reactivation wave
                 # below must re-gate against the freed headroom
                 self._notify_quota_pod_deleted(old)
             if old is not None and old.spec.node_name:
-                self.cache.remove_pod(old)
+                self._remove_bound_pod(old)
                 self.queue.move_all_to_active_or_backoff_queue(qevents.POD_DELETE)
             elif old is not None:
                 self.queue.delete(old)
             if old is not None:
                 self._notify_gang_pod_deleted(old)
+
+    def _remove_bound_pod(self, pod: Pod) -> None:
+        """A bound pod left the store: an external node-truth change, then
+        its removal from the cache."""
+        self._bump_external()
+        self.cache.remove_pod(pod)
 
     def _notify_gang_pod_deleted(self, pod: Pod) -> None:
         """PodGroup lifecycle on member deletion: the Coscheduling plugin's
@@ -443,10 +447,12 @@ class Scheduler:
             self._commit_plane = CommitPlane(self)
         return self._commit_plane
 
-    def _bump_external(self) -> None:
-        """Record one external node-truth change (see _external_events)."""
+    def _bump_external(self) -> int:
+        """Record one external node-truth change (see _external_events);
+        returns the sequence number it made."""
         with self._ext_mu:
             self._external_events += 1
+            return self._external_events
 
     def external_change_seq(self) -> int:
         """Monotonic count of external node-truth changes — the commit data

@@ -169,6 +169,27 @@ def test_zone_spread_rounds_compile_at_128_pods(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 28
 
 
+@pytest.mark.parametrize("topology", [False, True])
+def test_release_rows_compiles_at_headline_size(one_chip, headline_batch,
+                                                topology):
+    """The completion release (DeviceState.release) at 5000 nodes and 256
+    released pods: the scatter-subtract onto requested, nonzero_requested
+    and class_req, with the topology carry's signature counts or without."""
+    from kubernetes_tpu.backend.device_state import _release_rows_jit
+
+    _pb, _et, nt, tc, _tb = headline_batch
+    b = 256
+    s, r = np.shape(tc.sel_counts)[0], np.shape(nt.requested)[1]
+    args = _shapes((nt.requested, nt.nonzero_requested, nt.class_req,
+                    tc.sel_counts if topology else None,
+                    np.zeros(b, np.int32), np.zeros((b, r), np.int32),
+                    np.zeros((b, r), np.int32), np.zeros(b, np.int32),
+                    np.zeros((b, s), np.int32) if topology else None),
+                   one_chip)
+    compiled = _release_rows_jit.lower(*args).compile()
+    assert compiled.memory_analysis() is not None
+
+
 def test_sharded_schedule_batch_compiles_on_2x2_mesh(topo, headline_batch):
     """parallel/mesh.py's node-sharded program over the four described
     chips: node axis split four ways, winners reduced by collectives."""
